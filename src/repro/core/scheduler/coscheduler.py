@@ -28,9 +28,9 @@ bit-for-bit neutral:
   as device-resident jit arguments (uploaded once per engine) instead of
   being re-embedded as host constants at every trace.
 
-On a 1-device CPU test rig every group degenerates to the same device —
-multi-device behaviour is exercised via subprocess tests and the pod-slice
-dry-run.
+On a 1-device CPU test rig every group degenerates to the same device;
+multi-device behaviour runs under ``--xla_force_host_platform_device_count``
+on the CPU and on a four-chip host (``chip_smoke.py --chips 4``).
 """
 from __future__ import annotations
 
@@ -309,15 +309,17 @@ class SliceCoScheduler:
         return tuple(d.id for d in self._meshes[workload].devices.flat)
 
     def device_planes_for(self, workload: str, d: int):
-        """The engine's device-resident planes, re-homed onto this
-        co-scheduler's device group when pinned (passthrough otherwise —
-        the engine cache's default-device upload is already correct for an
-        unpinned slice, and re-uploading would double memory)."""
+        """The engine's device-resident planes, placed once on this
+        co-scheduler's device group.  Only an unpinned one-device slice
+        passes the engine's default-device upload through (re-uploading
+        there would double memory); on a pinned slice, or a workload group
+        of a multi-device slice, planes left on the default device would be
+        copied onto the group's devices at every launch."""
         key = (workload, d)
         planes = self._planes.get(key)
         if planes is None:
             planes = self.engine_for(workload, d).device_planes()
-            if self._pinned:
+            if self._pinned or len(self.devices) > 1:
                 sharding = NamedSharding(self._meshes[workload], P())
                 planes = jax.device_put(planes, sharding)
             self._planes[key] = planes

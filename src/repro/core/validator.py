@@ -248,12 +248,8 @@ def validate_fn(fn, *args, expected_passes: int | None = None,
     ``expect_eager=False`` alongside — a κ-amortised program intentionally
     defers folds out of the per-pass schedule V1/V2 police)."""
     lowered = jax.jit(fn, donate_argnums=donate_argnums).lower(*args)
-    compiled = lowered.compile()
-    try:
-        low_txt = lowered.as_text(debug_info=True)
-    except TypeError:  # older jax
-        low_txt = lowered.as_text()
-    return validate_module(low_txt, compiled.as_text(),
+    low_txt = lowered.as_text(debug_info=True)
+    return validate_module(low_txt, lowered.compile().as_text(),
                            expected_passes=expected_passes,
                            expect_eager=expect_eager,
                            expected_windows=expected_windows,

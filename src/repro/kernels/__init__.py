@@ -7,7 +7,10 @@
   planes never round-trip HBM (single-tenant fast path).
 
 ``pallas_tile_fn``/``pallas_fused_transform`` plug these into
-:func:`repro.core.limb_gemm.staged_transform`.
+:func:`repro.core.limb_gemm.staged_transform`.  The served path does not call
+them: ``SliceCoScheduler`` compiles ``engine.e2e``, whose staging passes are
+plain XLA dots and folds.  Every entry point compiles its kernel with Mosaic
+unless the caller passes ``interpret=True`` (the CPU tests do).
 """
 from __future__ import annotations
 
@@ -16,10 +19,23 @@ import jax.numpy as jnp
 
 from repro.kernels.limb_matmul.ops import limb_matmul
 from repro.kernels.mont_fold.ops import mont_fold, mont_fold_window_fn
-from repro.kernels.fused_ntt_tile.ops import fused_ntt_tile
+from repro.kernels.fused_ntt_tile.ops import diag_major, fused_ntt_tile
 
 
-def pallas_tile_fn(interpret: bool | None = None):
+def staging_passes() -> dict:
+    """Real staging-pass widths at d = 256, for the checks that compile and
+    run the kernels on the chip: per workload, the limb rows K (d_tile ×
+    data limbs), the degree d, the diagonal count and a channel modulus.
+    The limb GEMM's output is d × n_diag wide."""
+    from repro.core import field as F
+    from repro.core import rns as R
+    return {"dilithium": dict(k=171 * 3, d=256, n_diag=5,
+                              modulus=F.DILITHIUM_Q),
+            "bn254": dict(k=128 * 4, d=256, n_diag=7,
+                          modulus=R.make_chain(9).moduli[0])}
+
+
+def pallas_tile_fn(interpret: bool = False):
     """kernel_fn for staged_transform: Pallas limb matmul per staging pass."""
 
     def fn(a_tile_u32, w_planes_tile, fused_tile, plan):
@@ -35,13 +51,13 @@ def pallas_tile_fn(interpret: bool | None = None):
     return fn
 
 
-def fused_operand_3d(plan) -> np.ndarray:
-    """(d·La, d, n_diag) int8 layout for the fused kernel."""
-    return plan.fused_operand.reshape(
-        plan.d * plan.data_limbs, plan.d, plan.n_diag)
+def fused_operand_diag_major(plan) -> np.ndarray:
+    """(d·La, d·n_diag) int8 plan operand in the fused kernel's layout."""
+    return diag_major(plan.fused_operand.reshape(
+        plan.d * plan.data_limbs, plan.d, plan.n_diag))
 
 
-def pallas_fused_transform(a_u32, plan, *, interpret: bool | None = None):
+def pallas_fused_transform(a_u32, plan, *, interpret: bool = False):
     """Full staged transform with the fused matmul+fold kernel per pass.
 
     Eager per-pass folding (Invariant 5.1 ordering preserved in-kernel), but
@@ -50,14 +66,15 @@ def pallas_fused_transform(a_u32, plan, *, interpret: bool | None = None):
     from repro.core import field as F
     from repro.core import limbs as L
 
-    b3 = jnp.asarray(fused_operand_3d(plan))
+    b = jnp.asarray(fused_operand_diag_major(plan))
     m = jnp.uint32(plan.modulus)
     la = plan.data_limbs
     n = a_u32.shape[0]
     y = jnp.zeros((n, plan.d), jnp.uint32)
     for lo, hi in plan.tile_bounds():
         limbs = L.decompose_u8(a_u32[:, lo:hi], la).reshape(n, -1)
-        y_t = fused_ntt_tile(limbs, b3[lo * la:hi * la], modulus=plan.modulus,
-                             accum=plan.accum, interpret=interpret)
+        y_t = fused_ntt_tile(limbs, b[lo * la:hi * la], modulus=plan.modulus,
+                             n_diag=plan.n_diag, accum=plan.accum,
+                             interpret=interpret)
         y = F.addmod_u32(y, y_t, m)
     return y

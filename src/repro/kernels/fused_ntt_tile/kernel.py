@@ -9,6 +9,13 @@ completes (Invariant 5.1 is an ordering constraint, which the in-kernel
 sequencing preserves), but the paper's multi-tenant discipline keeps the
 phases in separate HLO ops — so this kernel is the single-tenant /
 relaxed-separation fast path (DESIGN.md §Perf).
+
+The twiddle operand arrives 2-D and diagonal-major inside each ``bd``-wide
+coefficient block (:func:`repro.kernels.fused_ntt_tile.ops.diag_major`):
+column ``j·bd·n_diag + k·bd + l`` holds coefficient ``j·bd + l`` of
+diagonal ``k``.  The epilogue then reads ``n_diag`` lane-aligned ``bd``-wide
+slices of the accumulator; a ``(bn, bd, n_diag)`` reshape with ``n_diag``
+as the lane dimension is a layout Mosaic cannot lower.
 """
 from __future__ import annotations
 
@@ -19,6 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.limb_matmul.kernel import (BK_EXACT_MAX, accumulate,
+                                              block_product)
+
 
 def _fused_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int, accum: str,
                   modulus: int, n_diag: int, bd: int):
@@ -26,42 +36,36 @@ def _fused_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int, accum: str,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bk = a_ref.shape[1]
-    b = b_ref[...].reshape(bk, bd * n_diag)
-    if accum == "fp32_mantissa":
-        acc_ref[...] += jax.lax.dot(a_ref[...].astype(jnp.float32),
-                                    b.astype(jnp.float32),
-                                    preferred_element_type=jnp.float32)
-    else:
-        acc_ref[...] += jax.lax.dot(a_ref[...].astype(jnp.int32),
-                                    b.astype(jnp.int32),
-                                    preferred_element_type=jnp.int32)
+    accumulate(acc_ref, block_product(a_ref, b_ref), accum)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _fold_and_flush():
         m = jnp.uint32(modulus)
-        diags = acc_ref[...].astype(jnp.int32).reshape(
-            acc_ref.shape[0], bd, n_diag)
-        acc = jnp.zeros((acc_ref.shape[0], bd), jnp.uint32)
+        acc = jnp.zeros(o_ref.shape, jnp.uint32)
         for k in range(n_diag - 1, -1, -1):
             for _ in range(8):
                 acc = acc << jnp.uint32(1)
                 acc = jnp.where(acc >= m, acc - m, acc)
-            dk = jnp.mod(diags[..., k], jnp.int32(modulus)).astype(jnp.uint32)
+            diag = acc_ref[:, k * bd:(k + 1) * bd].astype(jnp.int32)
+            dk = jnp.mod(diag, jnp.int32(modulus)).astype(jnp.uint32)
             s = acc + dk
             acc = jnp.where(s >= m, s - m, s)
         o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "modulus", "accum", "bn", "bd", "bk", "interpret"))
-def fused_ntt_tile_pallas(a_u8, b3_s8, *, modulus: int,
+    "modulus", "n_diag", "accum", "bn", "bd", "bk", "interpret"))
+def fused_ntt_tile_pallas(a_u8, b_s8, *, modulus: int, n_diag: int,
                           accum: str = "int32_native", bn: int = 128,
-                          bd: int = 128, bk: int = 128, interpret: bool = True):
-    """(N, K) u8 × (K, D, n_diag) s8 -> (N, D) uint32 folded mod m."""
+                          bd: int = 128, bk: int = 128,
+                          interpret: bool = False):
+    """(N, K) u8 × diagonal-major (K, D·n_diag) s8 -> (N, D) uint32 mod m."""
     n, k = a_u8.shape
-    k2, d, n_diag = b3_s8.shape
-    assert k == k2 and n % bn == 0 and d % bd == 0 and k % bk == 0
+    k2, cols = b_s8.shape
+    d = cols // n_diag
+    assert k == k2 and cols == d * n_diag
+    assert n % bn == 0 and d % bd == 0 and k % bk == 0
+    assert bk <= BK_EXACT_MAX, f"bk={bk} block products would round in f32"
     k_steps = k // bk
     acc_dtype = jnp.float32 if accum == "fp32_mantissa" else jnp.int32
 
@@ -71,10 +75,10 @@ def fused_ntt_tile_pallas(a_u8, b3_s8, *, modulus: int,
         grid=(n // bn, d // bd, k_steps),
         in_specs=[
             pl.BlockSpec((bn, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bd, n_diag), lambda i, j, kk: (kk, j, 0)),
+            pl.BlockSpec((bk, bd * n_diag), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bn, bd), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bn, bd * n_diag), acc_dtype)],
         interpret=interpret,
-    )(a_u8, b3_s8)
+    )(a_u8, b_s8)

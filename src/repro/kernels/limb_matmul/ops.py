@@ -1,7 +1,6 @@
 """jit'd wrapper for the limb matmul kernel: padding + dispatch."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.limb_matmul.kernel import limb_matmul_pallas
@@ -17,6 +16,9 @@ def _pad_to(x, axis: int, mult: int):
 
 
 def _pick_bn(n: int) -> int:
+    """Row block: 128, or the whole (power-of-two padded) row extent below
+    that — a block equal to the full dimension meets Mosaic's 8-bit tiling
+    at any height."""
     if n >= 128:
         return 128
     b = 8
@@ -26,14 +28,12 @@ def _pick_bn(n: int) -> int:
 
 
 def limb_matmul(a_u8, b_s8, *, accum: str = "int32_native",
-                interpret: bool | None = None):
+                interpret: bool = False):
     """(N, K) u8 × (K, M) s8 -> (N, M) int32 via the Pallas kernel.
 
     Pads every dim to MXU-aligned block multiples (exact: zero padding).
-    interpret defaults to True off-TPU (kernel body runs in Python on CPU).
+    ``interpret=True`` runs the kernel body in Python (CPU tests).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n, k = a_u8.shape
     m = b_s8.shape[1]
     bn = _pick_bn(n)

@@ -33,7 +33,7 @@ def _fold_kernel(d_ref, o_ref, *, modulus: int, n_diag: int):
 
 @functools.partial(jax.jit, static_argnames=("modulus", "bn", "bd", "interpret"))
 def mont_fold_pallas(diags, *, modulus: int, bn: int = 8, bd: int = 256,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """int32 (N, D, n_diag) -> uint32 (N, D): Σ_k diag_k·2^{8k} mod m."""
     n, d, n_diag = diags.shape
     assert n % bn == 0 and d % bd == 0, "ops.py must pad to block multiples"

@@ -1,30 +1,24 @@
 """jit'd wrapper for the VPU fold kernel."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.mont_fold.kernel import mont_fold_pallas
 
 
-def mont_fold(diags, modulus: int, *, interpret: bool | None = None):
-    """int32 (N, D, n_diag) -> uint32 (N, D) folded mod m."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def mont_fold(diags, modulus: int, *, interpret: bool = False):
+    """int32 (N, D, n_diag) -> uint32 (N, D) folded mod m.
+    ``interpret=True`` runs the kernel body in Python (CPU tests)."""
     n, d, n_diag = diags.shape
-    bn = min(8, n) if n % 8 else 8
-    bn = n if n < 8 else 8
-    pad_n = (-n) % bn
-    bd = min(256, d) if d % 256 else 256
-    bd = d if d < 256 else 256
-    pad_d = (-d) % bd
-    x = jnp.pad(diags, ((0, pad_n), (0, pad_d), (0, 0)))
+    bn = min(n, 8)
+    bd = min(d, 256)
+    x = jnp.pad(diags, ((0, (-n) % bn), (0, (-d) % bd), (0, 0)))
     out = mont_fold_pallas(x, modulus=modulus, bn=bn, bd=bd,
                            interpret=interpret)
     return out[:n, :d]
 
 
-def mont_fold_window_fn(*, interpret: bool | None = None):
+def mont_fold_window_fn(*, interpret: bool = False):
     """``fold_fn`` adapter for κ-window lazy mode.
 
     Returned callable has the ``fold_fn(acc_diag, modulus) -> uint32``

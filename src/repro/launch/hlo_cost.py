@@ -213,9 +213,5 @@ def corrected_cost(hlo_text: str) -> dict:
 
 
 def xla_cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict across jax versions (0.4.x
-    returns a one-element list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, ``{}`` where XLA reports none."""
+    return compiled.cost_analysis() or {}

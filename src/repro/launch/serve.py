@@ -98,7 +98,7 @@ def serve_crypto_online(*, duration_s=0.05, rate_hz=2048, n_c=8,
                         merge_dispatch=True, row_ladder_max=None,
                         donate=False, async_pipeline=False, warm_start=None,
                         controller=False, holdback_lambda=0.0,
-                        inflight_depth=1, compilation_cache_dir=None,
+                        inflight_depth=1,
                         telemetry_out=None, trace_out=None,
                         metrics_out=None, metrics_period_s=0.005,
                         metrics_port=None, deterministic_timing=False,
@@ -134,7 +134,6 @@ def serve_crypto_online(*, duration_s=0.05, rate_hz=2048, n_c=8,
                       controller=controller,
                       holdback_lambda=holdback_lambda,
                       inflight_depth=inflight_depth,
-                      compilation_cache_dir=compilation_cache_dir,
                       columnar_admission=columnar_admission,
                       tracing=trace_out is not None,
                       metrics=(metrics_out is not None
@@ -177,7 +176,6 @@ def serve_crypto_cluster(*, hosts=2, duration_s=0.05, rate_hz=2048, n_c=8,
                          donate=False, async_pipeline=False,
                          warm_start=None, controller=False,
                          holdback_lambda=0.0, inflight_depth=1,
-                         compilation_cache_dir=None,
                          telemetry_out=None, trace=None, trace_out=None,
                          metrics_out=None, metrics_period_s=0.005,
                          deterministic_timing=False,
@@ -216,7 +214,6 @@ def serve_crypto_cluster(*, hosts=2, duration_s=0.05, rate_hz=2048, n_c=8,
         donate=donate, async_pipeline=async_pipeline, warm_start=warm_start,
         controller=controller, holdback_lambda=holdback_lambda,
         inflight_depth=inflight_depth,
-        compilation_cache_dir=compilation_cache_dir,
         columnar_admission=columnar_admission,
         tracing=trace_out is not None,
         metrics=metrics_out is not None,
@@ -336,9 +333,6 @@ def main():
     ap.add_argument("--inflight-depth", type=int, default=1,
                     help="depth-k multi-flight launch ring per workload "
                          "class (k>1 requires --async-pipeline)")
-    ap.add_argument("--compilation-cache-dir", default=None,
-                    help="persist compiled programs here across process "
-                         "restarts (JAX compilation cache)")
     ap.add_argument("--arrival-batch", type=int, default=None,
                     help="feed the trace through the vectorised submit_many "
                          "ingress edge in chunks of this many arrivals "
@@ -348,6 +342,8 @@ def main():
                          "columnar (structured-array) admission state — the "
                          "bit-identical oracle path")
     args = ap.parse_args()
+    from repro.serve import enable_compilation_cache
+    enable_compilation_cache()
 
     reduction_by_workload = None
     if args.reduction_by_workload:
@@ -379,7 +375,6 @@ def main():
             controller=args.controller,
             holdback_lambda=args.holdback_lambda,
             inflight_depth=args.inflight_depth,
-            compilation_cache_dir=args.compilation_cache_dir,
             telemetry_out=args.telemetry_out, trace_out=args.trace_out,
             metrics_out=args.metrics_out,
             metrics_period_s=args.metrics_period_ms / 1e3,
@@ -465,7 +460,6 @@ def main():
             controller=args.controller,
             holdback_lambda=args.holdback_lambda,
             inflight_depth=args.inflight_depth,
-            compilation_cache_dir=args.compilation_cache_dir,
             telemetry_out=args.telemetry_out, trace_out=args.trace_out,
             metrics_out=args.metrics_out,
             metrics_period_s=args.metrics_period_ms / 1e3,

@@ -24,7 +24,9 @@ noise) per workload — tested in tests/test_obs.py and re-established after
 the exact cross-host merge in :func:`merge_penalty_sections`.
 
 The cycle model (device constants below are the v4-class geometry used by
-the paper's roofline): one launch of height R (``launched_rows``, rounded up
+the paper's roofline, on every device — the snapshot labels each entry
+``cycle_model: "tpu_v4_model"``; it is not a measurement of the serving
+chip): one launch of height R (``launched_rows``, rounded up
 to ``m_slots`` whole M tiles) over degree d with C channels costs
 
 * MXU: ``m_slots · d² · data_limbs · tw_limbs · C / MXU_MACS_PER_CYCLE``
@@ -39,6 +41,10 @@ MXU_MACS_PER_CYCLE = 128 * 128        # one v4-class 128×128 systolic pass
 VPU_LANES = 8 * 128                   # (8, 128) vector registers
 VPU_OPS_PER_DIAG = 4.0                # mul+add+shift+select per diagonal fold
 DEVICE_HZ = 940e6                     # v4 clock used by the paper's roofline
+# Every cycle count here is in this v4 model, whatever device served the
+# launch; each ``penalty`` entry carries the label so no reader takes the
+# bins for the serving device's own cycles.
+CYCLE_MODEL = "tpu_v4_model"
 
 SHARE_KEYS = ("mxu_productive", "arithmetic_stall", "spatial_pad", "host_gap")
 
@@ -162,6 +168,7 @@ class PenaltyLedger:
                 "live_rows": w["live_rows"],
                 "launched_rows": w["launched_rows"],
                 "reduction_modes": dict(w["reduction_modes"]),
+                "cycle_model": CYCLE_MODEL,
                 "cycles": cycles,
                 "shares": _shares(cycles),
             }
@@ -196,5 +203,6 @@ def merge_penalty_sections(sections) -> dict:
         out[name] = {**{k: a[k] for k in ("launches", "batches", "live_rows",
                                           "launched_rows")},
                      "reduction_modes": a["reduction_modes"],
+                     "cycle_model": CYCLE_MODEL,
                      "cycles": cycles, "shares": _shares(cycles)}
     return out

@@ -31,5 +31,6 @@ from repro.serve.batcher import ContinuousBatcher, ClosedBatch
 from repro.serve.client import LoadGenerator, LoadResult, attach_payloads
 from repro.serve.controller import AdaptiveController
 from repro.serve.server import (CryptoServer, RejectedError, ResponseHandle,
-                                ServeConfig, enable_compilation_cache)
+                                ServeConfig, compilation_cache_dir,
+                                enable_compilation_cache)
 from repro.serve.telemetry import BatchRecord, LatencyHistogram, Telemetry

@@ -212,26 +212,32 @@ class ServeConfig:
     # relative quantile error; count/mean/max stay exact).  None = exact
     # reservoir forever (the default — serving runs here are bounded).
     latency_sketch_bound: int | None = None
-    # persistent compile cache: point the JAX compilation cache at this
-    # directory so compiled programs survive process restarts — a cold boot
-    # then gets the same zero-trace first dispatch an in-process warm start
-    # does (pair with ``warm_start`` to populate it at first boot).
-    compilation_cache_dir: str | None = None
 
 
-def enable_compilation_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created if
-    missing) and lower the persistence thresholds so even fast CPU compiles
-    are cached — cold boots should warm from disk, not re-trace.  Safe to
-    call repeatedly; unknown tuning knobs on older jaxlibs are skipped."""
+# The persistent compile cache's home when the environment names none: a
+# fixed, git-ignored directory inside the checkout, so every run of one
+# checkout finds what the previous run compiled (a moving path never hits).
+CHECKOUT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def compilation_cache_dir() -> str:
+    """Where compiled programs persist: ``$JAX_COMPILATION_CACHE_DIR`` when
+    it is set, else the checkout's ``.jax_cache/``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+
+
+def enable_compilation_cache() -> str:
+    """Turn JAX's persistent compilation cache on at
+    :func:`compilation_cache_dir` and persist every compile, however fast,
+    so a cold boot warms from disk.  Call it before anything compiles (the
+    entry points do); safe to call repeatedly."""
+    cache_dir = compilation_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except (AttributeError, ValueError):
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
 
 
@@ -276,10 +282,6 @@ class CryptoServer:
                 "holdback_lambda > 0 needs merge_dispatch: holding a batch "
                 "for a merge partner is pointless if same-class batches "
                 "never coalesce along M")
-        # Persistent compile cache must be live before anything traces —
-        # the whole point is that precompile/warm_start below hit disk.
-        if cfg.compilation_cache_dir:
-            enable_compilation_cache(cfg.compilation_cache_dir)
         self.cos = coscheduler or coscheduler_from_config(cfg)
         self.controller = None
         if cfg.controller:

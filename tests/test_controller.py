@@ -23,7 +23,8 @@ from repro.core.scheduler.coscheduler import (MIN_ROW_TILE, SliceCoScheduler,
                                               validate_row_ladder)
 from repro.launch.serve import (serve_crypto, serve_crypto_cluster,
                                 serve_crypto_online)
-from repro.serve import CryptoServer, LoadGenerator, ServeConfig
+from repro.serve import (CryptoServer, LoadGenerator, ServeConfig,
+                         compilation_cache_dir, enable_compilation_cache)
 from repro.serve.controller import AdaptiveController
 
 RNG = np.random.default_rng(31)
@@ -370,23 +371,51 @@ def test_closed_loop_serving_matches_offline_replay_bitforbit():
 
 # --- satellite: persistent compile cache ----------------------------------------
 
-def test_compilation_cache_dir_configures_jax(tmp_path):
-    cache_dir = str(tmp_path / "xla-cache")
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        server = CryptoServer(_cfg(n_c=2, compilation_cache_dir=cache_dir),
-                              coscheduler=COS)
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        assert os.path.isdir(cache_dir)
-        h1 = server.submit(_dil_request(0, 64), now=0.0)
-        h2 = server.submit(_dil_request(1, 64), now=0.0)
-        assert h1.done() and h2.done()
-        eng = server.cos.engine_for("dilithium", 64)
-        iso = np.zeros((1, 64), np.uint32)
-        iso[0] = h1.request.coeffs
-        np.testing.assert_array_equal(h1.result(), eng.oracle_np(iso)[0])
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+@pytest.fixture
+def cache_config():
+    """Restore JAX's persistent-cache settings after a test turns it on."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_compilation_cache_dir_configures_jax(cache_config, monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    cache_dir = enable_compilation_cache()
+    assert cache_dir == os.path.join(ROOT, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == cache_dir
+    assert os.path.isdir(cache_dir)
+    server = CryptoServer(_cfg(n_c=2), coscheduler=COS)
+    h1 = server.submit(_dil_request(0, 64), now=0.0)
+    h2 = server.submit(_dil_request(1, 64), now=0.0)
+    assert h1.done() and h2.done()
+    eng = server.cos.engine_for("dilithium", 64)
+    iso = np.zeros((1, 64), np.uint32)
+    iso[0] = h1.request.coeffs
+    np.testing.assert_array_equal(h1.result(), eng.oracle_np(iso)[0])
+
+
+def test_compilation_cache_dir_follows_environment(cache_config, monkeypatch,
+                                                   tmp_path):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins when set, and compiles land
+    there; unset, the cache is the checkout's git-ignored ``.jax_cache/``."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compilation_cache_dir() == str(tmp_path)
+    cc.reset_cache()
+    assert enable_compilation_cache() == str(tmp_path)
+    jax.block_until_ready(jax.jit(lambda x: x * 3 + 11)(np.arange(5)))
+    assert os.listdir(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compilation_cache_dir() == os.path.join(ROOT, ".jax_cache")
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 # --- satellite: perf-report BENCH diffing ---------------------------------------

@@ -152,14 +152,23 @@ def test_pinned_placement_operand_planes_and_log():
         assert set(cos.device_ids(workload)) <= {target.id}
 
 
-@multi
 def test_unpinned_planes_passthrough():
+    """An unpinned one-device slice passes the engine's upload through (no
+    second copy); an unpinned multi-device slice places each workload
+    group's planes on that group's devices once, so no launch copies them
+    off the default device."""
     cos = SliceCoScheduler()
-    planes = cos.device_planes_for("dilithium", 64)
-    engine_planes = cos.engine_for("dilithium", 64).device_planes()
-    for a, b in zip(jax.tree_util.tree_leaves(planes),
-                    jax.tree_util.tree_leaves(engine_planes)):
-        assert a is b   # no re-upload, no extra device memory
+    for workload, d in (("dilithium", 64), ("bn254", 64)):
+        planes = cos.device_planes_for(workload, d)
+        engine_planes = cos.engine_for(workload, d).device_planes()
+        group = {dev for dev in cos._meshes[workload].devices.flat}
+        for a, b in zip(jax.tree_util.tree_leaves(planes),
+                        jax.tree_util.tree_leaves(engine_planes)):
+            if N_DEV == 1:
+                assert a is b   # no re-upload, no extra device memory
+            else:
+                assert a.devices() == group
+        assert cos.device_planes_for(workload, d) is planes
 
 
 # --- cluster-layer partitioning ------------------------------------------------

@@ -231,6 +231,7 @@ def test_merge_penalty_sections_exact():
                                     + sb["dilithium"]["cycles"][k])
     for w in merged.values():
         assert abs(sum(w["shares"].values()) - 1.0) <= 1e-9
+        assert w["cycle_model"] == "tpu_v4_model"   # modeled, never measured
 
 
 # --- sketch histograms ---------------------------------------------------------
