@@ -296,6 +296,37 @@ def summarize_failover(events: list[dict]) -> dict:
     return out
 
 
+def _merge_phases(snaps: list[dict]) -> dict:
+    """Leaf phases summed over hosts; the longest call is the fleet's."""
+    out: dict = {}
+    for s in snaps:
+        for name, p in s.get("phases", {}).items():
+            m = out.setdefault(name, {"seconds": 0.0, "calls": 0,
+                                      "longest_s": 0.0})
+            m["seconds"] += p["seconds"]
+            m["calls"] += p["calls"]
+            m["longest_s"] = max(m["longest_s"], p["longest_s"])
+    return dict(sorted(out.items()))
+
+
+def _merge_waits(snaps: list[dict]) -> dict:
+    """Per-class waits summed over hosts, means re-derived from the sums."""
+    out: dict = {}
+    for s in snaps:
+        for w, by_stage in s.get("waits", {}).items():
+            for stage, x in by_stage.items():
+                m = out.setdefault(w, {}).setdefault(
+                    stage, {"seconds": 0.0, "requests": 0, "longest_s": 0.0})
+                m["seconds"] += x["seconds"]
+                m["requests"] += x["requests"]
+                m["longest_s"] = max(m["longest_s"], x["longest_s"])
+    for by_stage in out.values():
+        for m in by_stage.values():
+            n = m["requests"]
+            m["mean_s"] = m["seconds"] / n if n else 0.0
+    return dict(sorted(out.items()))
+
+
 def merge_snapshots(snaps: list[dict]) -> dict:
     """Merge K per-host telemetry snapshots into one cluster snapshot.
 
@@ -337,6 +368,8 @@ def merge_snapshots(snaps: list[dict]) -> dict:
         "latency": _merge_histograms([s.get("latency") for s in snaps]),
         "queue_wait": _merge_histograms([s.get("queue_wait")
                                          for s in snaps]),
+        "phases": _merge_phases(snaps),
+        "waits": _merge_waits(snaps),
         "admission": {
             "admitted": sum(a.get("admitted", 0) for a in admission),
             "rejected": sum(a.get("rejected", 0) for a in admission),

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import limb_gemm as G
 from repro.core import workloads as WK
 from repro.core.scheduler.rectangular import StackedBatch, merge_operands
+from repro.obs.tracing import Phases
 
 # Bounded history of per-launch merge/padding records (the serving layer
 # drains it into telemetry after every dispatch; non-serving callers just
@@ -296,6 +297,9 @@ class SliceCoScheduler:
         # the anchored serving clock and dispatch_log entries carry a causal
         # launch ID ("lid") linking them to batch/request spans.
         self.tracer = None
+        # Leaf phases of each launch group (stage, call, d2h); the serving
+        # layer rebinds this to its own record.
+        self.phases = Phases()
 
     def reduction_for(self, workload: str) -> str:
         """The fold discipline this slice applies to a workload class."""
@@ -443,17 +447,20 @@ class SliceCoScheduler:
     def _launch(self, group: _LaunchGroup):
         """Enqueue one launch group on its workload's device group and return
         the in-flight device result without materialising it."""
-        eng = self.engine_for(group.workload, group.d_bucket)
-        members = [self._member_operand(b, eng)
-                   for _, b, _, _ in group.members]
-        rows = self.launch_rows(group.operand_rows)
-        if len(members) == 1 and members[0].shape[0] == rows:
-            operand_np = members[0]        # singleton at a rung: no host copy
-        else:
-            operand_np = merge_operands(members, n_rows=rows)
-        operand = self._shard(group.workload, jnp.asarray(operand_np))
-        out = self.jitted_for(group.workload, group.d_bucket)(
-            operand, self.device_planes_for(group.workload, group.d_bucket))
+        with self.phases.stage:
+            eng = self.engine_for(group.workload, group.d_bucket)
+            members = [self._member_operand(b, eng)
+                       for _, b, _, _ in group.members]
+            rows = self.launch_rows(group.operand_rows)
+            if len(members) == 1 and members[0].shape[0] == rows:
+                operand_np = members[0]    # singleton at a rung: no host copy
+            else:
+                operand_np = merge_operands(members, n_rows=rows)
+            operand = self._shard(group.workload, jnp.asarray(operand_np))
+            program = self.jitted_for(group.workload, group.d_bucket)
+            planes = self.device_planes_for(group.workload, group.d_bucket)
+        with self.phases.call:
+            out = program(operand, planes)
         tr = self.tracer
         if tr is not None:
             group.lid = tr.next_id()
@@ -478,7 +485,8 @@ class SliceCoScheduler:
         """Gather one group's device result and split it back into one
         :class:`DispatchResult` per member batch (ladder-pad rows dropped,
         rows routed by position within each member's slice)."""
-        res = np.asarray(out)
+        with self.phases.d2h:
+            res = np.asarray(out)
         tr = self.tracer
         if tr is not None:
             tr.end("launch", group.lid,

@@ -5,7 +5,9 @@ Three pillars (the measurement substrate every perf PR is judged against):
 * :mod:`tracing`  — low-overhead request-lifecycle tracing: a bounded
   ring-buffer :class:`Tracer` collecting span/instant/counter events with
   causal request/batch/launch IDs, emitted by the server, batcher,
-  co-scheduler, and cluster layers (host-tagged in cluster mode);
+  co-scheduler, and cluster layers (host-tagged in cluster mode); and
+  :class:`Phases`, leaf phase spans of the serving path on the JAX
+  profiler's clock with running (seconds, calls, longest) counters;
 * :mod:`export`   — Chrome ``trace_event`` / Perfetto rendering of a trace
   (open the JSON in https://ui.perfetto.dev), with per-host process tracks,
   per-class device tracks for launch groups, and counter tracks for queue
@@ -36,11 +38,11 @@ from repro.obs.ledger import (PenaltyLedger, launch_cycles,
                               merge_penalty_sections)
 from repro.obs.metrics import (MetricsRegistry, expose_registries,
                                serve_metrics_http)
-from repro.obs.tracing import Tracer
+from repro.obs.tracing import Phases, Tracer
 from repro.obs.validate import validate_chrome_trace, validate_openmetrics
 
 __all__ = [
-    "Tracer", "chrome_trace", "write_chrome_trace", "PenaltyLedger",
+    "Tracer", "Phases", "chrome_trace", "write_chrome_trace", "PenaltyLedger",
     "merge_penalty_sections", "launch_cycles", "validate_chrome_trace",
     "validate_openmetrics", "MetricsRegistry", "expose_registries",
     "serve_metrics_http", "AlertEngine", "BurnRateRule", "ThresholdRule",

@@ -25,9 +25,10 @@ import json
 
 # Stable thread ordering inside each host process: lifecycle first, then the
 # device/dispatch tracks, cluster control (drain barrier, failover spans),
-# counters and alerts last.  Unknown tracks sort after these.
+# counters and alerts, then the leaf phases.  Unknown tracks sort after
+# these.
 _TRACK_ORDER = ("serve", "batcher", "holdback", "device", "cluster",
-                "failover", "counters", "alerts")
+                "failover", "counters", "alerts", "phases")
 
 
 def open_text(path: str, mode: str = "rt"):

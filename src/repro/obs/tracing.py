@@ -24,11 +24,22 @@ Event phases follow the Chrome ``trace_event`` vocabulary the exporter
 targets: ``"i"`` instant, ``"b"``/``"e"`` async span begin/end (async spans
 of one category may overlap — requests and depth-k launch rings do),
 ``"B"``/``"E"`` stack-scoped sync spans, ``"C"`` counter sample.
+
+**Phases.**  :class:`Phases` times a few leaf phases of the serving path
+(admission, batching, dispatch bookkeeping, the co-scheduler's staging,
+program call and device-to-host wait) once per call, never per request.
+Each phase lands on the JAX profiler's host plane as ``repro.<phase>``, on
+the clock of the device ops, and in a running record of (seconds, calls,
+longest); with a :class:`Tracer` attached it is also a ``B``/``E`` pair on
+the ``phases`` track.  Phases never nest, so each phase's sum is its self
+time.
 """
 from __future__ import annotations
 
 import collections
 import time
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 1 << 16
 
@@ -41,6 +52,17 @@ ID_STRIDE = 1 << 40
 CAT_REQUEST = "request"
 CAT_BATCH = "batch"
 CAT_LAUNCH = "launch"
+
+# Profiler span names are PHASE_PREFIX + phase, so none can equal a span
+# that a caller (a benchmark harness) wraps around the server's calls.
+PHASE_PREFIX = "repro."
+PHASE_TRACK = "phases"
+# The server's phases, then the co-scheduler's (per launch group).
+PHASES = ("admit", "enqueue", "validate", "account", "resolve",
+          "stage", "call", "d2h")
+
+_profiling = TraceAnnotation.is_enabled
+_clock = time.perf_counter
 
 
 class Tracer:
@@ -149,3 +171,55 @@ class Tracer:
         """Ring-buffer audit for the telemetry export."""
         return {"events": len(self.events), "dropped": self.dropped,
                 "capacity": self.capacity}
+
+
+class _Phase:
+    """One phase's span; reused for every call (phases never nest, so a
+    phase is never entered while it is open)."""
+
+    __slots__ = ("name", "label", "rec", "tracer", "ann", "t0")
+
+    def __init__(self, name: str, rec: list, tracer: Tracer | None):
+        self.name, self.rec, self.tracer = name, rec, tracer
+        self.label = PHASE_PREFIX + name
+        self.ann = None
+
+    def __enter__(self):
+        if _profiling():
+            self.ann = TraceAnnotation(self.label)
+        if self.tracer is not None:
+            self.tracer.emit("B", self.name, self.tracer.wall_now(),
+                             track=PHASE_TRACK)
+        self.t0 = _clock()
+
+    def __exit__(self, *exc):
+        dt = _clock() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        if self.tracer is not None:
+            self.tracer.emit("E", self.name, self.tracer.wall_now(),
+                             track=PHASE_TRACK)
+        rec = self.rec
+        rec[0] += dt
+        rec[1] += 1
+        if dt > rec[2]:
+            rec[2] = dt
+
+
+class Phases:
+    """Leaf phase timer: ``with phases.stage: ...``, one attribute per
+    name in :data:`PHASES`.
+
+    ``record`` maps a phase name to ``[seconds, calls, longest seconds]``,
+    updated in place (the serving telemetry's ``live["phases"]``, so a
+    window's share is a subtraction of two copies).  The profiler span is
+    opened only while a profiler session records; with none, a phase costs
+    two clock reads and a few list updates."""
+
+    def __init__(self, record: dict | None = None,
+                 tracer: Tracer | None = None):
+        self.record = {} if record is None else record
+        for name in PHASES:
+            rec = self.record.setdefault(name, [0.0, 0, 0.0])
+            setattr(self, name, _Phase(name, rec, tracer))

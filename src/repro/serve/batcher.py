@@ -61,6 +61,7 @@ class ClosedBatch:
     batch: StackedBatch
     reason: str
     age_s: float             # oldest-row residency at close time
+    closed_at: float         # serving clock of the close
     batch_id: int = 0        # causal batch ID (0 when tracing is off)
 
 
@@ -204,4 +205,4 @@ class ContinuousBatcher:
                              requests=ob.requests, operand=operand)
         return ClosedBatch(batch=batch, reason=reason,
                            age_s=max(0.0, now - ob.opened_at),
-                           batch_id=ob.bid)
+                           closed_at=now, batch_id=ob.bid)

@@ -36,7 +36,7 @@ from repro.core.scheduler.rectangular import packing_metrics
 from repro.obs.alerts import AlertEngine, default_serve_rules
 from repro.obs.ledger import PenaltyLedger, launch_cycles
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.tracing import Tracer
+from repro.obs.tracing import Phases, Tracer
 from repro.serve.admission import AdmissionController, AdmissionDecision
 from repro.serve.batcher import CLOSE_DRAIN, ClosedBatch, ContinuousBatcher
 from repro.serve.controller import AdaptiveController
@@ -318,6 +318,10 @@ class CryptoServer:
             columnar=cfg.columnar_admission)
         self.telemetry = telemetry or Telemetry(
             sketch_bound=cfg.latency_sketch_bound)
+        # Leaf phase spans of this server and its co-scheduler, counted in
+        # the telemetry's live record.  Handed to the co-scheduler at every
+        # launch and gather, since cluster hosts may share one.
+        self.phases = Phases(self.telemetry.live["phases"], self.tracer)
         if self.controller is not None:
             self.telemetry.attach_section("controller",
                                           self.controller.snapshot)
@@ -355,7 +359,7 @@ class CryptoServer:
         # priced to wait for a partner.
         self._staged: list[ClosedBatch] = []
         # ring key -> deque of (launch seq, closed, InflightDispatch,
-        # launch log, launch_s)
+        # launch log, launch_s, launched_at)
         self._rings: dict = collections.OrderedDict()
         self._launch_seq = 0
         # class key -> (ClosedBatch, release_at, held_at, hid)
@@ -485,17 +489,60 @@ class CryptoServer:
             if len(nows_arr) != len(reqs):
                 raise ValueError(f"nows has {len(nows_arr)} entries for "
                                  f"{len(reqs)} requests")
+        if not len(reqs):
+            return []
+        tr = self.tracer
+        if tr is not None:
+            tr.anchor(float(nows_arr[0]))
+        with self.phases.admit:
+            handles, live_pos, dec = self._admit_many(reqs, nows_arr, tr)
+        if dec is None:
+            return handles
+        # Each decision applied in arrival order: a refused handle is
+        # rejected, an admitted request joins the handle table and its open
+        # batch (closes and operand stacking included).
+        closed: list[ClosedBatch] = []
+        with self.phases.enqueue:
+            admitted = dec.admitted
+            for j, p in enumerate(live_pos):
+                req, t = reqs[p], float(nows_arr[p])
+                if not admitted[j]:
+                    d = dec.decision(j)
+                    if tr is not None:
+                        tr.instant("reject", t, args={"workload": req.workload,
+                                                      "reason": d.reason})
+                    handles[p]._reject(d, at=t)
+                    continue
+                if tr is not None:
+                    tid = tr.next_id()
+                    req.trace_id = tid
+                    name = self._req_span_names.get(req.workload)
+                    if name is None:
+                        name = self._req_span_names.setdefault(
+                            req.workload, "req:" + req.workload)
+                    tr.begin("request", tid, name, t)
+                rid = getattr(req, "request_id", None)
+                if rid is not None:
+                    self._seen_rids.add(rid)
+                self._handles[id(req)] = handles[p]
+                closed.extend(self.batcher.add(req, t))
+        self._dispatch(closed, float(nows_arr[-1]))
+        return handles
+
+    def _admit_many(self, reqs, nows_arr, tr) -> tuple:
+        """``submit_many``'s admission: the handles, the duplicate screen
+        and the vectorised decisions.  Returns the handles, the positions
+        screened in and their decisions; None for the decisions when the
+        batch was refused whole (draining, or all duplicates), which skips
+        the dispatch edge."""
         handles = [ResponseHandle(r, submitted_at=float(t))
                    for r, t in zip(reqs, nows_arr)]
-        if not handles:
-            return handles
-        tr = self.tracer
         if self._draining:
             d = AdmissionDecision(False, "draining")
             for h, t in zip(handles, nows_arr):
                 h._reject(d, at=float(t))
             self.telemetry.record_admissions({"draining": len(reqs)})
-            return handles
+            return handles, (), None
         live_pos, dup_pos, seen, seen_rids = [], [], set(), set()
         for p, r in enumerate(reqs):
             oid = id(r)
@@ -519,7 +566,7 @@ class CryptoServer:
                                      "reason": "duplicate"})
         if not live_pos:
             self.telemetry.record_admissions({"duplicate": len(dup_pos)})
-            return handles
+            return handles, (), None
         cluster_pending = (
             self.cluster_depth_fn(float(nows_arr[live_pos[0]]))
             if (self.cluster_depth_fn is not None
@@ -532,32 +579,7 @@ class CryptoServer:
         if dup_pos:
             counts["duplicate"] = len(dup_pos)
         self.telemetry.record_admissions(counts)
-        closed: list[ClosedBatch] = []
-        admitted = dec.admitted
-        for j, p in enumerate(live_pos):
-            req, t = reqs[p], float(nows_arr[p])
-            if not admitted[j]:
-                d = dec.decision(j)
-                if tr is not None:
-                    tr.instant("reject", t, args={"workload": req.workload,
-                                                  "reason": d.reason})
-                handles[p]._reject(d, at=t)
-                continue
-            if tr is not None:
-                tid = tr.next_id()
-                req.trace_id = tid
-                name = self._req_span_names.get(req.workload)
-                if name is None:
-                    name = self._req_span_names.setdefault(
-                        req.workload, "req:" + req.workload)
-                tr.begin("request", tid, name, t)
-            rid = getattr(req, "request_id", None)
-            if rid is not None:
-                self._seen_rids.add(rid)
-            self._handles[id(req)] = handles[p]
-            closed.extend(self.batcher.add(req, t))
-        self._dispatch(closed, float(nows_arr[-1]))
-        return handles
+        return handles, live_pos, dec
 
     @property
     def pending_load(self) -> int:
@@ -571,7 +593,7 @@ class CryptoServer:
         if self._held:
             load += sum(cb.batch.n_c for cb, _, _, _ in self._held.values())
         for ring in self._rings.values():
-            for _, part, _, _, _ in ring:
+            for _, part, *_ in ring:
                 load += sum(cb.batch.n_c for cb in part)
         return load
 
@@ -587,7 +609,10 @@ class CryptoServer:
         Under the async pipeline this is also the gathering edge: any launch
         left in flight by a previous event is materialised here."""
         now = time.monotonic() if now is None else now
-        closed = self.batcher.poll(now)
+        if self.tracer is not None:
+            self.tracer.anchor(now)
+        with self.phases.enqueue:
+            closed = self.batcher.poll(now)
         self._dispatch(closed, now)
         return len(closed)
 
@@ -624,7 +649,10 @@ class CryptoServer:
         the cluster barrier calls ``quiesce`` on all hosts first, then this."""
         now = time.monotonic() if now is None else now
         self.quiesce(now)
-        closed = self.batcher.flush(now)
+        if self.tracer is not None:
+            self.tracer.anchor(now)
+        with self.phases.enqueue:
+            closed = self.batcher.flush(now)
         self._dispatch(closed, now, final=True)
         return len(closed)
 
@@ -724,6 +752,10 @@ class CryptoServer:
         key = (batch.workload, batch.d_bucket)
         if key in self._validated:
             return
+        with self.phases.validate:
+            self._validate(batch, key)
+
+    def _validate(self, batch, key: tuple):
         eng = self.cos.engine_for(batch.workload, batch.d_bucket)
         rows = (batch.operand.shape[0] if batch.operand is not None
                 else batch.n_c)
@@ -798,7 +830,20 @@ class CryptoServer:
         m.describe("repro_latency_seconds", "gauge",
                    "Request latency quantiles.", wall=True)
         m.describe("repro_queue_wait_seconds", "gauge",
-                   "Queue-wait quantiles.", wall=True)
+                   "Queue-wait quantiles: admission to launch.", wall=True)
+        m.describe("repro_phase_seconds_total", "counter",
+                   "Host seconds in each leaf phase of the serving path.",
+                   wall=True)
+        m.describe("repro_phase_calls_total", "counter",
+                   "Calls of each leaf phase of the serving path.")
+        m.describe("repro_wait_seconds_total", "counter",
+                   "Request waits per class by stage: admission to close, "
+                   "close to launch, launch to resolve.")
+        m.describe("repro_wait_requests_total", "counter",
+                   "Requests counted in repro_wait_seconds_total.")
+        m.describe("repro_wait_longest_seconds", "gauge",
+                   "Longest request wait per class and stage since the "
+                   "last reset.")
         m.describe("repro_penalty_share", "gauge",
                    "Modeled-cycle share per penalty bin (all workloads).",
                    wall=True)
@@ -842,6 +887,20 @@ class CryptoServer:
         if live["dispatches"]:
             out.append(("repro_dispatch_m_occupancy", (),
                         live["m_occupancy_sum"] / live["dispatches"]))
+        # Phase seconds are wall-clock even under deterministic timing, which
+        # promises series that are functions of the trace alone: withheld.
+        wall = not self.config.deterministic_timing
+        for name, (secs, calls, _) in live["phases"].items():
+            ph = (("phase", name),)
+            out.append(("repro_phase_calls_total", ph, calls))
+            if wall:
+                out.append(("repro_phase_seconds_total", ph, secs))
+        for w, by_stage in live["waits"].items():
+            for stage, (secs, n, longest) in by_stage.items():
+                lab = (("class", w), ("stage", stage))
+                out.append(("repro_wait_seconds_total", lab, secs))
+                out.append(("repro_wait_requests_total", lab, n))
+                out.append(("repro_wait_longest_seconds", lab, longest))
         if len(self.telemetry.latency):
             for q in (50, 95, 99):
                 out.append(("repro_latency_seconds", (("q", f"p{q}"),),
@@ -983,7 +1042,7 @@ class CryptoServer:
             ring = self._rings[key] = collections.deque()
         return ring
 
-    def _launch_staged(self, staged: list[ClosedBatch]) -> set:
+    def _launch_staged(self, staged: list[ClosedBatch], now: float) -> set:
         """Enqueue the staged set onto the launch ring(s) and return the
         ring keys launched.  Depth 1 keeps the whole event in one flight
         (cross-class groups share one launch_mixed — the PR-4 pipeline);
@@ -1003,7 +1062,7 @@ class CryptoServer:
         for key, part in parts:
             self._launch_seq += 1
             self._ring_for(key).append((self._launch_seq, part,
-                                        *self._launch(part)))
+                                        *self._launch(part), now))
         return {key for key, _ in parts}
 
     def _oldest_ring(self) -> collections.deque | None:
@@ -1041,12 +1100,12 @@ class CryptoServer:
         if not self.config.async_pipeline:
             if self._staged:
                 staged, self._staged = self._staged, []
-                self._finish(staged, *self._launch(staged), now)
+                self._finish(staged, *self._launch(staged), now, now)
         else:
             launched_keys = set()
             if self._staged:
                 staged, self._staged = self._staged, []
-                launched_keys = self._launch_staged(staged)
+                launched_keys = self._launch_staged(staged, now)
             if final:
                 # Retire the full ring in launch order — drain leaves
                 # nothing in flight (the cluster barrier counts on it).
@@ -1077,6 +1136,7 @@ class CryptoServer:
         self._scrape_metrics(now, final=final)
 
     def _launch(self, staged: list[ClosedBatch]):
+        self.cos.phases = self.phases
         t0 = time.perf_counter()
         flight = self.cos.launch_mixed([cb.batch for cb in staged])
         launch_s = time.perf_counter() - t0
@@ -1088,15 +1148,28 @@ class CryptoServer:
         return flight, log, launch_s
 
     def _finish(self, closed: list[ClosedBatch], flight, log: list,
-                launch_s: float, now: float):
+                launch_s: float, launched_at: float, now: float):
         # Service time = launch enqueue + blocking gather.  The async idle
         # gap between the two events is deliberately excluded: feeding it to
         # the admission EWMA would inflate the per-row service estimate by
         # the event spacing and make the SLO gate reject load the slice can
         # trivially carry.
+        self.cos.phases = self.phases
         t1 = time.perf_counter()
         results = self.cos.gather(flight)
-        service_s = launch_s + time.perf_counter() - t1
+        gathered_s = time.perf_counter() - t1
+        with self.phases.account:
+            resolved = self._account(closed, flight, results, log,
+                                     launch_s + gathered_s, now)
+        with self.phases.resolve:
+            self._resolve_handles(resolved, launched_at, now)
+
+    def _account(self, closed: list[ClosedBatch], flight, results,
+                 log: list, service_s: float, now: float) -> list:
+        """Everything a gathered launch settles besides its handles: the
+        service estimate, packing metrics, dispatch and batch records, and
+        the penalty ledger.  Returns ``(closed batch, result, completion
+        clock)`` per batch for :meth:`_resolve_handles`."""
         if self.dispatch_auditor is not None:
             self.dispatch_auditor.on_gather(flight)
         if self.config.deterministic_timing:
@@ -1191,6 +1264,7 @@ class CryptoServer:
                 service_s=service_s * live / total_live,
                 profile=self._ledger_profile(*key),
                 k_occupancy=(acc[0] / acc[1]) if acc and acc[1] else 1.0)
+        resolved = []
         for (cb, eng, m), res in zip(batch_metrics, results):
             batch = cb.batch
             share = service_s * batch.n_c / total_rows
@@ -1202,15 +1276,62 @@ class CryptoServer:
                 age_s=cb.age_s,
                 reduction=eng.fold_profile["reduction"],
                 n_folds=eng.fold_profile["n_folds"]))
-            completed = now + share
+            resolved.append((cb, res, now + share))
+        return resolved
+
+    def _resolve_handles(self, resolved: list, launched_at: float,
+                         now: float):
+        """Resolve each batch's handles, and count each request's latency,
+        its queue wait (admission to launch) and its class's waits."""
+        telemetry, tr = self.telemetry, self.tracer
+        # workload -> [requests, admission-to-close seconds, the longest of
+        # those, close clock summed per request, earliest close]
+        by_class: dict = {}
+        for cb, res, completed in resolved:
+            batch = cb.batch
+            skipped, submitted, first = 0, 0.0, float("inf")
             for i, r in enumerate(batch.requests):
                 handle = self._handles.pop(id(r), None)
                 if handle is None:       # direct batcher use, no submit()
+                    skipped += 1
                     continue
                 # route by row position — a tenant may own several rows
                 handle._resolve(res.rows[i], completed)
-                self.telemetry.observe_latency(
-                    handle.latency_s, queue_wait_s=now - handle.submitted_at)
+                t = handle.submitted_at
+                submitted += t
+                if t < first:
+                    first = t
+                telemetry.observe_latency(handle.latency_s,
+                                          queue_wait_s=launched_at - t)
                 rid = getattr(r, "trace_id", None)
                 if tr is not None and rid is not None:
                     tr.end("request", rid, "complete", completed)
+            n = len(batch.requests) - skipped
+            if not n:
+                continue
+            closed_at = cb.closed_at
+            acc = by_class.get(batch.workload)
+            if acc is None:
+                by_class[batch.workload] = [n, closed_at * n - submitted,
+                                            closed_at - first,
+                                            closed_at * n, closed_at]
+                continue
+            acc[0] += n
+            acc[1] += closed_at * n - submitted
+            acc[2] = max(acc[2], closed_at - first)
+            acc[3] += closed_at * n
+            acc[4] = min(acc[4], closed_at)
+        for workload, (n, close_s, close_max, closed_sum,
+                       closed_min) in by_class.items():
+            waits = telemetry.wait_record(workload)
+            for stage, total, longest in (
+                    ("to_close", close_s, close_max),
+                    ("to_launch", launched_at * n - closed_sum,
+                     launched_at - closed_min),
+                    ("to_resolve", (now - launched_at) * n,
+                     now - launched_at)):
+                rec = waits[stage]
+                rec[0] += total
+                rec[1] += n
+                if longest > rec[2]:
+                    rec[2] = longest

@@ -12,6 +12,11 @@ import dataclasses
 import json
 import math
 
+# Per-class waits, each a stretch of a request's time in the server on the
+# serving clock: admission to its batch's close, close to the launch that
+# carried it, and launch to the serving event that resolved it.
+WAIT_STAGES = ("to_close", "to_launch", "to_resolve")
+
 
 class LatencyHistogram:
     """Latency reservoir with interpolated percentiles.
@@ -197,6 +202,7 @@ class Telemetry:
         self.batches: list[BatchRecord] = []
         self.dispatches: list[DispatchRecord] = []
         self.latency = LatencyHistogram(sketch_bound=sketch_bound)
+        # Per request, admission to the launch that carried it.
         self.queue_wait = LatencyHistogram(sketch_bound=sketch_bound)
         self.admission_counts: dict[str, int] = {}
         self._queue_depth_sum = 0
@@ -221,6 +227,11 @@ class Telemetry:
             "live_rows": 0,
             "launched_rows": 0,
             "m_occupancy_sum": 0.0,    # over DispatchRecords
+            # phase -> [seconds, calls, longest]: the serving path's leaf
+            # phases (repro.obs.tracing.Phases writes these in place)
+            "phases": {},
+            # workload -> {WAIT_STAGES entry: [seconds, requests, longest]}
+            "waits": {},
         }
 
     def attach_section(self, name: str, provider):
@@ -249,6 +260,25 @@ class Telemetry:
         live["live_rows"] += rec.live_rows
         live["launched_rows"] += rec.launched_rows
         live["m_occupancy_sum"] += rec.m_occupancy
+
+    def wait_record(self, workload: str) -> dict:
+        """The running per-class wait record, created on first use; the
+        server updates it in place for every request it resolves."""
+        rec = self.live["waits"].get(workload)
+        if rec is None:
+            rec = self.live["waits"][workload] = {
+                k: [0.0, 0, 0.0] for k in WAIT_STAGES}
+        return rec
+
+    def reset_longest(self):
+        """Start a new interval for the longest phase and the longest wait
+        (sums and counts keep running, so an interval's share of those is a
+        subtraction)."""
+        for rec in self.live["phases"].values():
+            rec[2] = 0.0
+        for by_stage in self.live["waits"].values():
+            for rec in by_stage.values():
+                rec[2] = 0.0
 
     def record_admission(self, reason: str):
         self.admission_counts[reason] = self.admission_counts.get(reason, 0) + 1
@@ -356,6 +386,12 @@ class Telemetry:
         admitted = self.admission_counts.get("ok", 0)
         rejected = sum(v for k, v in self.admission_counts.items() if k != "ok")
         extra = {name: provider() for name, provider in self._sections.items()}
+        phases = {name: {"seconds": s, "calls": n, "longest_s": m}
+                  for name, (s, n, m) in sorted(self.live["phases"].items())}
+        waits = {w: {k: {"seconds": s, "requests": n, "longest_s": m,
+                         "mean_s": s / n if n else 0.0}
+                     for k, (s, n, m) in by_stage.items()}
+                 for w, by_stage in sorted(self.live["waits"].items())}
         return {
             **extra,
             "holdback": dict(self.holdback),
@@ -374,6 +410,8 @@ class Telemetry:
             "per_workload": per_workload,
             "latency": self.latency.summary(include_samples),
             "queue_wait": self.queue_wait.summary(include_samples),
+            "phases": phases,
+            "waits": waits,
             "admission": {"admitted": admitted, "rejected": rejected,
                           "by_reason": dict(self.admission_counts)},
         }

@@ -475,10 +475,34 @@ def test_trace_and_metrics_gzip_roundtrip(tmp_path):
     mstats = validate_openmetrics(mpath)
     assert mstats["samples"] > 0
     assert read_text(mpath) == srv.metrics_text()
+    # phase calls and per-class waits are exported; phase seconds are
+    # wall-clock, withheld under deterministic timing
+    series = {line.split("{")[0] for line in srv.metrics_text().splitlines()
+              if line and not line.startswith("#")}
+    assert {"repro_phase_calls_total", "repro_wait_seconds_total",
+            "repro_wait_requests_total",
+            "repro_wait_longest_seconds"} <= series
+    assert "repro_phase_seconds_total" not in series
+    assert 'stage="to_close"' in srv.metrics_text()
     # plain-path round trip through the same helpers
     plain = str(tmp_path / "metrics.om")
     write_text(plain, srv.metrics_text())
     assert validate_openmetrics(plain) == mstats
+
+
+def test_phase_seconds_exported_on_the_wall_clock():
+    srv = CryptoServer(_cfg(deterministic_timing=False), coscheduler=COS)
+    for i in range(12):
+        srv.submit(_dil_request(i, 64, i * 0.001), now=i * 0.001)
+    srv.drain(0.02)
+    text = srv.metrics_text()
+    assert validate_openmetrics(text)["samples"] > 0
+    assert 'repro_phase_seconds_total{phase="stage"}' in text
+    assert 'repro_phase_calls_total{phase="resolve"}' in text
+    assert 'repro_wait_longest_seconds{class="dilithium",stage="to_close"}' \
+        in text
+    assert 'repro_wait_requests_total{class="dilithium",stage="to_resolve"}' \
+        in text
 
 
 # --- perf_report penalty-share drift -------------------------------------------
