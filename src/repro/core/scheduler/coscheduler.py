@@ -39,7 +39,6 @@ import dataclasses
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import limb_gemm as G
@@ -275,6 +274,11 @@ class SliceCoScheduler:
             w: Mesh(np.asarray(devs), ("rows",))
             for w, devs in assignment.items()
         }
+        # (workload, split rows?) -> the operand sharding, built once.
+        self._shardings: dict = {}
+        # Operand device_put calls (launches, warm-up, validation); each
+        # dispatch_log record carries its launch's share.
+        self.placements = 0
         self._engines: dict = {}
         self._jitted: dict = {}
         # (workload, d_bucket) -> device-resident twiddle/fused planes.
@@ -395,23 +399,35 @@ class SliceCoScheduler:
             planes = self.device_planes_for(workload, d)
             before = self.trace_counts.get(key, 0)
             for rung in rungs:
-                operand = jnp.zeros(self.operand_shape(workload, d, rung),
-                                    jnp.uint32)
+                operand = np.zeros(self.operand_shape(workload, d, rung),
+                                   np.uint32)
                 out = self.jitted_for(workload, d)(
                     self._shard(workload, operand), planes)
                 jax.block_until_ready(out)
             n_new += self.trace_counts.get(key, 0) - before
         return n_new
 
-    def _shard(self, workload: str, operand: jnp.ndarray):
+    def _sharding(self, workload: str, rows: int) -> NamedSharding:
+        """Operand sharding of a ``rows``-tall launch on ``workload``'s
+        device group: rows split over the group (``P("rows")``) when they
+        divide evenly over more than one device, else whole on each."""
         mesh = self._meshes[workload]
-        n_dev = mesh.devices.size
-        rows = operand.shape[0]
-        if rows % n_dev == 0 and n_dev > 1:
-            spec = P("rows")
-        else:
-            spec = P()
-        return jax.device_put(operand, NamedSharding(mesh, spec))
+        split = mesh.devices.size > 1 and rows % mesh.devices.size == 0
+        sharding = self._shardings.get((workload, split))
+        if sharding is None:
+            sharding = NamedSharding(mesh, P("rows") if split else P())
+            self._shardings[(workload, split)] = sharding
+        return sharding
+
+    def _shard(self, workload: str, operand):
+        """Place an operand on its workload's device group with one
+        ``device_put``.  A host (numpy) operand goes straight to each of
+        its shards; launches, warm-up and validation all come through
+        here, so their operands share one committed sharding and hence
+        one executable."""
+        self.placements += 1
+        return jax.device_put(operand,
+                              self._sharding(workload, operand.shape[0]))
 
     # --- group planning + launch ----------------------------------------------
 
@@ -456,7 +472,8 @@ class SliceCoScheduler:
                 operand_np = members[0]    # singleton at a rung: no host copy
             else:
                 operand_np = merge_operands(members, n_rows=rows)
-            operand = self._shard(group.workload, jnp.asarray(operand_np))
+            placed = self.placements
+            operand = self._shard(group.workload, operand_np)
             program = self.jitted_for(group.workload, group.d_bucket)
             planes = self.device_planes_for(group.workload, group.d_bucket)
         with self.phases.call:
@@ -478,7 +495,9 @@ class SliceCoScheduler:
             "n_batches": len(group.members), "live_rows": group.live_rows,
             "launched_rows": int(operand_np.shape[0]),
             "donated": self.donate, "lid": group.lid,
-            "devices": self.device_ids(group.workload)})
+            "devices": self.device_ids(group.workload),
+            "staged_bytes": int(operand_np.nbytes),
+            "placements": self.placements - placed})
         return group, eng, out
 
     def _materialise(self, group: _LaunchGroup, eng, out):

@@ -128,11 +128,12 @@ def merge_operands(operands: list[np.ndarray],
     """
     total = sum(op.shape[0] for op in operands)
     rows = total if n_rows is None else max(n_rows, total)
-    out = np.zeros((rows,) + operands[0].shape[1:], operands[0].dtype)
+    out = np.empty((rows,) + operands[0].shape[1:], operands[0].dtype)
     lo = 0
     for op in operands:
         out[lo:lo + op.shape[0]] = op
         lo += op.shape[0]
+    out[lo:] = 0    # only the pad rows: each byte is written once
     return out
 
 

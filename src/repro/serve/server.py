@@ -26,7 +26,6 @@ import os
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import validator as V
@@ -766,8 +765,7 @@ class CryptoServer:
         # funnel: on a pinned slice the validation trace must see committed
         # arrays on *its* device — mixing a default-device operand with
         # pinned planes is an XLA device-mismatch error, not a validation.
-        args = (self.cos._shard(batch.workload,
-                                jnp.zeros(shape, jnp.uint32)),
+        args = (self.cos._shard(batch.workload, np.zeros(shape, np.uint32)),
                 self.cos.device_planes_for(batch.workload, batch.d_bucket))
         donate = (0,) if self.cos.donate else ()
 
@@ -825,6 +823,10 @@ class CryptoServer:
                    "Rows queued, held, or in flight (the admission view).")
         m.describe("repro_inflight_groups", "gauge",
                    "Launch groups on the async ring awaiting gather.")
+        m.describe("repro_stage_bytes_total", "counter",
+                   "Operand bytes staged onto the device by launches.")
+        m.describe("repro_stage_placements_total", "counter",
+                   "Operand device_put calls of launches (one a launch).")
         m.describe("repro_dispatch_m_occupancy", "gauge",
                    "Mean achieved per-launch M occupancy (live/N_c_max).")
         m.describe("repro_latency_seconds", "gauge",
@@ -884,6 +886,8 @@ class CryptoServer:
         for reason, n in live["close_reasons"].items():
             out.append(("repro_batches_closed_total",
                         (("reason", reason),), n))
+        out.append(("repro_stage_bytes_total", (), live["staged_bytes"]))
+        out.append(("repro_stage_placements_total", (), live["placements"]))
         if live["dispatches"]:
             out.append(("repro_dispatch_m_occupancy", (),
                         live["m_occupancy_sum"] / live["dispatches"]))
@@ -1255,7 +1259,9 @@ class CryptoServer:
                 m_occupancy=min(1.0, live / self.config.n_c_max),
                 m_fill=live / launched if launched else 0.0,
                 donated=entry["donated"],
-                devices=tuple(entry.get("devices", ()))))
+                devices=tuple(entry.get("devices", ())),
+                staged_bytes=entry["staged_bytes"],
+                placements=entry["placements"]))
             acc = class_k.get(key)
             self.ledger.observe_launch(
                 workload=entry["workload"], d=entry["d_bucket"],

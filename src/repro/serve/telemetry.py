@@ -174,6 +174,8 @@ class DispatchRecord:
     donated: bool = False    # operand buffer donated to the program
     devices: tuple = ()      # device ids the launch was enqueued on (empty
                              # for records predating device pinning)
+    staged_bytes: int = 0    # host operand bytes put on the device
+    placements: int = 0      # device_put calls that staged the operand
 
 
 @dataclasses.dataclass(frozen=True)
@@ -226,6 +228,8 @@ class Telemetry:
             "dispatches": 0,
             "live_rows": 0,
             "launched_rows": 0,
+            "staged_bytes": 0,
+            "placements": 0,
             "m_occupancy_sum": 0.0,    # over DispatchRecords
             # phase -> [seconds, calls, longest]: the serving path's leaf
             # phases (repro.obs.tracing.Phases writes these in place)
@@ -259,6 +263,8 @@ class Telemetry:
         live["dispatches"] += 1
         live["live_rows"] += rec.live_rows
         live["launched_rows"] += rec.launched_rows
+        live["staged_bytes"] += rec.staged_bytes
+        live["placements"] += rec.placements
         live["m_occupancy_sum"] += rec.m_occupancy
 
     def wait_record(self, workload: str) -> dict:
@@ -371,6 +377,8 @@ class Telemetry:
             "m_fill_mean": (sum(r.m_fill for r in self.dispatches) / n_d)
                            if n_d else 0.0,
             "donated": sum(1 for r in self.dispatches if r.donated),
+            "staged_bytes": sum(r.staged_bytes for r in self.dispatches),
+            "placements": sum(r.placements for r in self.dispatches),
         }
         # Per-device launch census (device-parallel fleets): which device
         # ids this host's programs were enqueued on, and how many live rows
