@@ -21,7 +21,9 @@ Every answered row is compared with the plain reference of its class:
 
 Every number is a count with the limit 0: rows wrong, and admitted requests
 never answered.  A row whose length is not its bucket, or with a digit of
-12 bits or more, counts as wrong.
+12 bits or more, counts as wrong.  A fleet's delivery guarantee adds two:
+answers from another host than the tenant's owner (the rendezvous hash of
+``bench/reference/rendezvous.py``), and requests answered more than once.
 """
 from __future__ import annotations
 
@@ -30,6 +32,7 @@ import numpy as np
 from bench import payloads as PL
 from bench.reference import bn254 as BN
 from bench.reference import dilithium as DIL
+from bench.reference import rendezvous as RV
 
 DIGIT_BITS = 12
 LIMB_BITS = 16
@@ -172,3 +175,17 @@ def run_checks(records, rng: np.random.Generator) -> dict:
         checks["bn254_rows_wrong"] = {"value": bn_wrong, "limit": 0}
     return {"checks": checks,
             "compared": {"dilithium_rows": n_dil, "bn254_rows": n_bn}}
+
+
+def check_delivery(answers, n_hosts: int) -> dict:
+    """answers: (host, tenant ids) for every batch a host answered.  Counts
+    the answers that came from another host than the tenant's owner, and
+    the tenants answered more than once."""
+    seen, twice, off_owner = set(), 0, 0
+    for host, tenants in answers:
+        for t in tenants:
+            twice += t in seen
+            seen.add(t)
+            off_owner += RV.owner(t, n_hosts) != host
+    return {"answered_off_owner": {"value": off_owner, "limit": 0},
+            "answered_twice": {"value": twice, "limit": 0}}

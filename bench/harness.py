@@ -1,15 +1,18 @@
 """The server under test, built from a configuration, and the loops that
 drive it.
 
-The window drives ``CryptoServer.submit_many`` / ``pump``: admission, the
-continuous batcher, the co-scheduler and the engines.  Timers and profiler
-spans sit around those calls and around the co-scheduler's
-``launch_mixed`` / ``gather``, installed from here on the server's objects;
-nothing in the program is edited.
+The window drives ``submit_many`` / ``pump`` of a ``CryptoServer`` or, for a
+configuration with a ``cluster`` block, of a ``ClusterServer`` over one host
+slice per chip: the router, each host's admission and continuous batcher,
+its co-scheduler and the engines.  Timers and profiler spans sit around
+those calls and around every co-scheduler's ``launch_mixed`` / ``gather``,
+installed from here on the server's objects; nothing in the program is
+edited.
 """
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import dataclasses
 import time
@@ -21,19 +24,25 @@ QUEUE_FULL = "queue_full"       # the admission gate's reason at max_pending
 
 
 class Probes:
-    """Host timers and records around the co-scheduler's two calls.
+    """Host timers and records around every co-scheduler's two calls.
 
     ``resolved`` collects the requests whose results a ``gather`` returned;
     the loops empty it after every server call and date those completions
     at the moment the call returned, which is when a caller sees them."""
 
-    def __init__(self, cos, annotate: bool):
+    def __init__(self, coss: list, annotate: bool):
         import jax
         self.annotate = annotate
         self._annotation = jax.profiler.TraceAnnotation
         self.launch_s = self.gather_s = 0.0
-        self.launches: list = []     # (workload, d_bucket, rows, live_rows)
+        # (workload, d_bucket, rows, live_rows, host)
+        self.launches: list = []
         self.resolved: list = []
+        self.answered: list = []     # (host, requests of a gathered batch)
+        for host, cos in enumerate(coss):
+            self._wrap(cos, host)
+
+    def _wrap(self, cos, host: int):
         launch_mixed, gather = cos.launch_mixed, cos.gather
 
         def timed_launch(batches):
@@ -44,7 +53,7 @@ class Probes:
             for g, _, _ in flight.groups:
                 self.launches.append((g.workload, g.d_bucket,
                                       cos.launch_rows(g.operand_rows),
-                                      g.live_rows))
+                                      g.live_rows, host))
             return flight
 
         def timed_gather(flight):
@@ -54,6 +63,7 @@ class Probes:
             self.gather_s += clock() - t
             for dr in results:
                 self.resolved.extend(dr.batch.requests)
+                self.answered.append((host, dr.batch.requests))
             return results
 
         cos.launch_mixed, cos.gather = timed_launch, timed_gather
@@ -66,23 +76,33 @@ class Probes:
         self.launch_s = self.gather_s = 0.0
         self.launches.clear()
         self.resolved.clear()
+        self.answered.clear()
 
 
-def build_server(cfg: dict, device):
-    """A ``CryptoServer`` on one device, with a ``SliceCoScheduler`` made
-    from the configuration's serving settings and classes."""
+def build_server(cfg: dict, devices: list):
+    """The configuration's server on ``devices``: without a ``cluster``
+    block, one ``CryptoServer`` on the first device with a
+    ``SliceCoScheduler`` made from the serving settings and classes; with
+    one, a ``ClusterServer`` of one host slice per device, each host with
+    such a co-scheduler pinned to its own chip.
+
+    The hosts share one table of compiled programs and one record of the
+    programs validated: a program is traced, lowered and checked by the
+    HLO validator once for the fleet, and compiled for each chip."""
     from repro.core.scheduler.coscheduler import (SliceCoScheduler,
                                                   default_row_ladder)
     from repro.serve.server import CryptoServer, ServeConfig
     s = cfg["serving"]
     classes = [c["workload"] for c in cfg["classes"]]
     folds = {w: cfg["guarantees"]["fold"][w] for w in classes}
-    cos = SliceCoScheduler(
-        assignment={w: [device] for w in classes}, accum=s["accum"],
-        reduction="eager", reduction_by_workload=folds, d_tile=s["d_tile"],
-        merge=s["merge_dispatch"],
-        row_ladder=default_row_ladder(s["row_ladder_max"]),
-        donate=s["donate"])
+
+    def coscheduler(device, **pin):
+        return SliceCoScheduler(
+            assignment={w: [device] for w in classes}, accum=s["accum"],
+            reduction="eager", reduction_by_workload=folds,
+            d_tile=s["d_tile"], merge=s["merge_dispatch"],
+            row_ladder=default_row_ladder(s["row_ladder_max"]),
+            donate=s["donate"], **pin)
     scfg = ServeConfig(
         n_c=s["n_c"], max_age_s=s["max_age_s"], validate=s["validate"],
         accum=s["accum"], max_pending=s["max_pending"],
@@ -90,11 +110,53 @@ def build_server(cfg: dict, device):
         merge_dispatch=s["merge_dispatch"],
         row_ladder_max=s["row_ladder_max"], donate=s["donate"],
         async_pipeline=s["async_pipeline"])
-    return CryptoServer(scfg, coscheduler=cos), cos
+    fleet = cfg.get("cluster")
+    if fleet is None:
+        return CryptoServer(scfg, coscheduler=coscheduler(devices[0]))
+    from repro.cluster import ClusterConfig, ClusterServer
+    n = fleet["n_hosts"]
+    if len(devices) != n:
+        raise ValueError(f"{n} host slices need {n} devices, one each; "
+                         f"got {len(devices)}")
+    coss = [coscheduler(dev, host=h, devices=[dev])
+            for h, dev in enumerate(devices)]
+    for cos in coss[1:]:
+        cos._jitted = coss[0]._jitted
+    server = ClusterServer(
+        ClusterConfig(n_hosts=n, device_parallel=fleet["device_parallel"],
+                      gossip_period_s=fleet["gossip_period_s"],
+                      fault_plan=fleet["fault_plan"],
+                      shed_watermark=fleet["shed_watermark"], serve=scfg),
+        coscheduler_factory=coss.__getitem__)
+    for srv in server.hosts[1:]:
+        srv._validated = server.hosts[0]._validated
+    return server
 
 
-def moduli_for(cos):
+def hosts_of(server) -> list:
+    """The ``CryptoServer`` of each host slice: a cluster's hosts, or the
+    one server."""
+    return getattr(server, "hosts", None) or [server]
+
+
+def coschedulers(server) -> list:
+    return [srv.cos for srv in hosts_of(server)]
+
+
+def owner_of(server):
+    """tenant id -> index of the host that serves it."""
+    router = getattr(server, "router", None)
+    return router.host_for if router is not None else (lambda tenant: 0)
+
+
+def inflight_groups(server) -> int:
+    return sum(srv.inflight_groups for srv in hosts_of(server))
+
+
+def moduli_for(server):
     """A BN254 class's RNS channel moduli: the server's input encoding."""
+    cos = coschedulers(server)[0]
+
     def get(workload):
         return cos.engine_for(workload, 64).chain.moduli
     return get
@@ -105,29 +167,72 @@ def buckets(server, cls: dict) -> list:
                    for d in range(cls["degree_low"], cls["degree_high"] + 1)})
 
 
-def warm_up(server, cos, cfg: dict, request_type) -> int:
+def _warm_host(srv, cfg: dict, request_type) -> int:
     """One launch of every (class, bucket, ladder rung) the traffic can
-    produce, through the server's own path: a ``submit_many`` of one rung's
+    produce, through one host's own path: a ``submit_many`` of one rung's
     worth of full rows closes rung / n_c batches that merge into one launch
     of that height, and the first of each (class, bucket) runs the HLO
     validator.  Returns the launches made."""
-    mods = moduli_for(cos)
+    mods = moduli_for(srv)
     made = 0
     for cls in cfg["classes"]:
         w = cls["workload"]
-        for b in buckets(server, cls):
+        for b in buckets(srv, cls):
             shape = (b,) if w == "dilithium" else (b, len(mods(w)))
-            for rung in cos.row_ladder:
+            for rung in srv.cos.row_ladder:
                 reqs = [request_type(tenant_id=-1, workload=w, degree=b,
                                      arrival_time=0.0,
                                      coeffs=np.zeros(shape, np.uint32))
                         for _ in range(rung)]
-                server.submit_many(reqs, now=clock())
-                server.pump(clock())
+                srv.submit_many(reqs, now=clock())
+                srv.pump(clock())
                 made += 1
-    while server.inflight_groups:
-        server.pump(clock())
+    while srv.inflight_groups:
+        srv.pump(clock())
     return made
+
+
+def _lower(cos, programs: list):
+    """Trace and lower every program at every ladder rung, compiling
+    nothing: the lowered module is the same for every chip of a fleet.
+    (The co-scheduler's own jitted programs, whatever wraps its
+    ``jitted_for``.)"""
+    for w, d in programs:
+        program = type(cos).jitted_for(cos, w, d)
+        planes = cos.device_planes_for(w, d)
+        for rung in cos.row_ladder:
+            operand = np.zeros(cos.operand_shape(w, d, rung), np.uint32)
+            program.lower(cos._shard(w, operand), planes)
+
+
+def warm_up(server, cfg: dict, request_type, marks: dict) -> int:
+    """Warm every host slice on every shape its traffic can produce; returns
+    the launches made, and marks the clock in ``marks["compiled"]`` when
+    every program is compiled for every chip (and validated).
+
+    In a fleet, the first host traces and lowers every program once; then
+    it warms through its own path (compiling for its chip, and running the
+    HLO validator) while the other hosts compile the same programs for
+    theirs, all at once (the compiler runs outside the interpreter lock).
+    Last, each other host makes the same launches through its own path,
+    which finds every program compiled and validated."""
+    hosts = hosts_of(server)
+    if len(hosts) == 1:
+        made = _warm_host(hosts[0], cfg, request_type)
+        marks["compiled"] = clock()
+        return made
+    programs = [(c["workload"], b) for c in cfg["classes"]
+                for b in buckets(hosts[0], c)]
+    _lower(hosts[0].cos, programs)
+    with concurrent.futures.ThreadPoolExecutor(len(hosts) - 1) as ex:
+        done = [ex.submit(srv.cos.precompile, programs, srv.config.n_c)
+                for srv in hosts[1:]]
+        made = _warm_host(hosts[0], cfg, request_type)
+        for f in done:
+            f.result()
+    marks["compiled"] = clock()
+    return made + sum(_warm_host(srv, cfg, request_type)
+                      for srv in hosts[1:])
 
 
 @dataclasses.dataclass
@@ -226,7 +331,7 @@ class _Loop:
         """Pump an expired age deadline, or gather a launch in flight when
         nothing else is due.  Returns whether a call was made."""
         dl = self.server.next_deadline()
-        if (dl is not None and dl <= now_abs) or self.server.inflight_groups:
+        if (dl is not None and dl <= now_abs) or inflight_groups(self.server):
             _, t_ret = self.call("pump", self.server.pump, now_abs)
             self.collect(t_ret)
             return True
@@ -244,17 +349,45 @@ class _Loop:
                             calls=self.calls)
 
 
-def _resubmit(lp: _Loop, requests: list, held: collections.deque,
+class _Held:
+    """Requests refused for a full queue, held in arrival order in one
+    queue per host slice, the one whose router sends them there."""
+
+    def __init__(self, server, requests: list):
+        self.owner, self.requests = owner_of(server), requests
+        self.queues = [collections.deque() for _ in hosts_of(server)]
+
+    def __bool__(self) -> bool:
+        return any(self.queues)
+
+    def extend(self, ks):
+        for k in ks:
+            self.queues[self.owner(self.requests[k].tenant_id)].append(k)
+
+    def restore(self, ks: list):
+        """Put ``ks`` (ascending) back at the front of their queues."""
+        for k in reversed(ks):
+            self.queues[self.owner(self.requests[k].tenant_id)].appendleft(k)
+
+    def indices(self) -> list:
+        return [k for q in self.queues for k in q]
+
+
+def _resubmit(lp: _Loop, requests: list, held: _Held,
               now_abs: float) -> bool:
-    """Submit held requests, oldest first, as far as the server's queue has
-    room; returns whether any was admitted."""
-    server = lp.server
-    room = min(server.admission.max_pending - server.pending_load, len(held))
-    if room <= 0:
+    """Submit held requests, oldest first, as far as the queue of the host
+    that owns each has room: a request whose host is still full stays held,
+    whatever room the other hosts have.  Returns whether any was
+    admitted."""
+    take = []
+    for srv, q in zip(hosts_of(lp.server), held.queues):
+        room = min(srv.admission.max_pending - srv.pending_load, len(q))
+        take += [q.popleft() for _ in range(room)]
+    if not take:
         return False
-    take = [held.popleft() for _ in range(room)]
+    take.sort()
     full = lp.submit([requests[k] for k in take], now_abs, retry=True)
-    held.extendleft(reversed(full))
+    held.restore(full)
     return len(full) < len(take)
 
 
@@ -266,16 +399,17 @@ def run_open(server, requests: list, due: np.ndarray, seconds: float,
 
     A request the server refuses for a full queue is held, as a client
     that honours the refusal holds it, and submitted again as the queue
-    makes room, oldest first, with every later arrival queued behind it;
-    its latency still runs from its due time.  After the last arrival the
-    loop keeps serving until every request has resolved, at most
-    ``grace_s`` past the window; one still held then counts as rejected.
+    makes room, oldest first, with every later arrival for the same host
+    queued behind it; its latency still runs from its due time.  After
+    the last arrival the loop keeps serving until every request has
+    resolved, at most ``grace_s`` past the window; one still held then
+    counts as rejected.
     Request ``i`` carries tenant id ``i``."""
     n = len(requests)
     lp = _Loop(server, probes, n)
     lp.due[:] = due
     lp.pool[:] = np.arange(n)
-    held: collections.deque = collections.deque()
+    held = _Held(server, requests)
     i = 0
     lp.t0 = t0 = clock()
     while True:
@@ -303,7 +437,7 @@ def run_open(server, requests: list, due: np.ndarray, seconds: float,
             targets.append(dl - t0)
         if targets:
             _wait_until(min(targets), t0, probes)
-    lp.rejected[list(held)] = True
+    lp.rejected[held.indices()] = True
     return lp.result(n, seconds)
 
 

@@ -17,7 +17,7 @@ def read(ctx):
         return None
     d_tile = ctx["cfg"]["serving"]["d_tile"]
     macs = bytes_ = 0
-    for workload, d, rows, _ in launches:
+    for workload, d, rows, *_ in launches:
         c = RF.launch_cost(workload, d, rows, d_tile)
         macs += c["macs"]
         bytes_ += c["bytes"]

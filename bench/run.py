@@ -29,6 +29,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -140,9 +141,8 @@ class Session:
     wl: dict
     cfg: dict
     mix: dict
-    device: object
+    devices: list
     server: object
-    cos: object
     probes: object
     request_type: type
     compile_s: dict
@@ -151,16 +151,30 @@ class Session:
     marks: dict
 
 
+def _time_validation(srv, validate_s: list, lock):
+    """Add the seconds of each of the server's HLO validations to
+    ``validate_s[0]``."""
+    validate_once = srv._validate_once
+
+    def timed_validate(batch):
+        t = time.perf_counter()
+        validate_once(batch)
+        with lock:
+            validate_s[0] += time.perf_counter() - t
+    srv._validate_once = timed_validate
+
+
 def setup(bench: dict, wl: dict, cfg: dict, mix: dict, *, trace: bool,
           require_tpu: bool = True, control: bool = False,
           setup_hook=None, cache: bool = True) -> Session:
-    """Build the server of a cell's configuration on the first device and
-    warm up every shape its traffic can produce.
+    """Build the server of a cell's configuration on the first ``chips``
+    devices and warm up every shape its traffic can produce.
 
-    ``setup_hook(server, cos)`` runs after the server is built (the tests
-    plant faults through it); ``control`` runs the configuration's control
-    in the program's place; ``cache`` keeps compiled programs in the
-    checkout's ``.jax_cache/``."""
+    ``setup_hook(server, cos)`` runs after the server is built, once for
+    each host slice's server and co-scheduler (the tests plant faults
+    through it); ``control`` runs the configuration's control in the
+    program's place; ``cache`` keeps compiled programs in the checkout's
+    ``.jax_cache/``."""
     if control and cfg["control"]["kind"] == "class_swap":
         cfg = apply_control(cfg)
     import jax
@@ -177,38 +191,60 @@ def setup(bench: dict, wl: dict, cfg: dict, mix: dict, *, trace: bool,
         from repro.serve import enable_compilation_cache
         os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
         cache_dir = enable_compilation_cache()
+        # No cap on its size: a cap (JAX_COMPILATION_CACHE_MAX_SIZE) below
+        # a cell's programs evicts them, least recently used first, so
+        # every run compiles them all again.
+        jax.config.update("jax_compilation_cache_max_size", -1)
     marks = {"init": time.perf_counter()}
 
     compile_s: dict = {}
+    lock = threading.Lock()      # the warm-up compiles on several threads
 
     def on_duration(event, secs, **_):
         key = COMPILE_EVENTS.get(event)
         if key:
-            compile_s[key] = compile_s.get(key, 0.0) + secs
-            compile_s["n_" + key] = compile_s.get("n_" + key, 0) + 1
+            with lock:
+                compile_s[key] = compile_s.get(key, 0.0) + secs
+                compile_s["n_" + key] = compile_s.get("n_" + key, 0) + 1
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
-    server, cos = H.build_server(cfg, devs[0])
+    devs = devs[:wl["chips"]]
+    server = H.build_server(cfg, devs)
     validate_s = [0.0]
-    validate_once = server._validate_once
-
-    def timed_validate(batch):
-        t = time.perf_counter()
-        validate_once(batch)
-        validate_s[0] += time.perf_counter() - t
-    server._validate_once = timed_validate
-    if control and cfg["control"]["kind"] == "reference_f32":
-        install_f32_control(cos, cfg["control"]["workload"])
-    if setup_hook is not None:
-        setup_hook(server, cos)
-    probes = H.Probes(cos, annotate=trace)
+    for srv in H.hosts_of(server):
+        _time_validation(srv, validate_s, lock)
+        if control and cfg["control"]["kind"] == "reference_f32":
+            install_f32_control(srv.cos, cfg["control"]["workload"])
+        if setup_hook is not None:
+            setup_hook(srv, srv.cos)
+    probes = H.Probes(H.coschedulers(server), annotate=trace)
     marks["built"] = time.perf_counter()
-    marks["warm_launches"] = H.warm_up(server, cos, cfg, TenantRequest)
+    marks["warm_launches"] = H.warm_up(server, cfg, TenantRequest, marks)
     marks["warm"] = time.perf_counter()
-    return Session(bench=bench, wl=wl, cfg=cfg, mix=mix, device=devs[0],
-                   server=server, cos=cos, probes=probes,
+    return Session(bench=bench, wl=wl, cfg=cfg, mix=mix, devices=devs,
+                   server=server, probes=probes,
                    request_type=TenantRequest, compile_s=compile_s,
                    validate_s=validate_s, cache_dir=cache_dir, marks=marks)
+
+
+TELEMETRY = ("dispatches", "live_rows", "launched_rows", "requests_served")
+
+
+def _traces(coss) -> int:
+    return sum(sum(cos.trace_counts.values()) for cos in coss)
+
+
+def _telemetry(hosts) -> dict:
+    """The servers' dispatch counters, summed over host slices."""
+    return {k: sum(srv.telemetry.live[k] for srv in hosts) for k in TELEMETRY}
+
+
+def _admissions(hosts) -> dict:
+    out: dict = {}
+    for srv in hosts:
+        for k, v in srv.telemetry.admission_counts.items():
+            out[k] = out.get(k, 0) + v
+    return out
 
 
 def window(sess: Session, seed: int, seconds: float, trace: bool,
@@ -218,8 +254,9 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     line's object.  ``mix`` overrides the cell's mix (for sweeps)."""
     import jax
     mix = mix or sess.mix
-    cfg, server, cos, probes = sess.cfg, sess.server, sess.cos, sess.probes
-    cell, dev, compile_s = sess.wl["name"], sess.device, sess.compile_s
+    cfg, server, probes = sess.cfg, sess.server, sess.probes
+    cell, devs, compile_s = sess.wl["name"], sess.devices, sess.compile_s
+    hosts, coss = H.hosts_of(server), H.coschedulers(server)
     t_pay = time.perf_counter()
     rng = np.random.default_rng(seed)
     if mix["loop"] == "open":
@@ -227,7 +264,7 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     else:
         sched = TR.closed_pool(cfg["classes"], TR.pool_size(mix), rng)
     served, truth = PL.make_payloads(sched.workloads, sched.degrees, rng,
-                                     H.moduli_for(cos))
+                                     H.moduli_for(server))
     requests = [sess.request_type(tenant_id=i, workload=w, degree=int(d),
                                   arrival_time=0.0, coeffs=c)
                 for i, (w, d, c) in enumerate(zip(sched.workloads,
@@ -238,10 +275,10 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     # collection inside the window walks only what the server allocates.
     gc.collect()
     gc.freeze()
-    traces_before = sum(cos.trace_counts.values())
+    traces_before = _traces(coss)
     compiles_before = compile_s.get("n_backend_compile", 0)
-    live0 = dict(server.telemetry.live)
-    adm0 = dict(server.telemetry.admission_counts)
+    live0 = _telemetry(hosts)
+    adm0 = _admissions(hosts)
     probes.reset()
 
     trace_dir = os.path.join(OUT_DIR, "trace")
@@ -272,12 +309,13 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     gc.unfreeze()
     if trace:
         jax.profiler.stop_trace()
-    mem_peak = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
-    traces_in_window = sum(cos.trace_counts.values()) - traces_before
+    mem_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in devs)
+    traces_in_window = _traces(coss) - traces_before
     compiles_in_window = (compile_s.get("n_backend_compile", 0)
                           - compiles_before)
-    live1 = server.telemetry.live
-    adm1 = server.telemetry.admission_counts
+    live1 = _telemetry(hosts)
+    adm1 = _admissions(hosts)
 
     # --- what the window did ---------------------------------------------
     n = len(win.rows)
@@ -303,16 +341,18 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
                         None if row is None else np.asarray(row),
                         bool(answered[i])))
     chk = CHK.run_checks(records, np.random.default_rng([seed, 1]))
+    if "cluster" in cfg:
+        chk["checks"].update(CHK.check_delivery(
+            ((h, [r.tenant_id for r in reqs]) for h, reqs in probes.answered),
+            len(hosts)))
     ref_s = time.perf_counter() - t_ref
     correct = all(c["value"] <= c["limit"] for c in chk["checks"].values())
 
+    dev = devs[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()),
-              "memory_peak_bytes": mem_peak}
+              "count": len(devs), "memory_peak_bytes": mem_peak}
     ctx = {"window": win, "probes": probes, "cfg": cfg,
-           "telemetry": {k: live1[k] - live0[k]
-                         for k in ("dispatches", "live_rows",
-                                   "launched_rows", "requests_served")},
+           "telemetry": {k: live1[k] - live0[k] for k in TELEMETRY},
            "n_submitted": n, "trace": None, "busy_s": None,
            "device_kind": dev.device_kind}
     out = {"correct": correct, "attempted": n,
@@ -323,7 +363,8 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
         from bench import trace_reduce as TRR
         t_red = time.perf_counter()
         summ = TRR.load(TRR.find_xplane(trace_dir))
-        ctx.update(trace=summ, busy_s=TRR.busy_s(summ))
+        by_chip = list(TRR.busy_by_device(summ).values())
+        ctx.update(trace=summ, busy_s=sum(by_chip) / max(1, len(by_chip)))
         device.update(busy_s=ctx["busy_s"], window_s=summ.window_s)
         for m in metrics_for(sess.bench, cell, "per_layer"):
             v = load_reader(m["name"])(ctx)
@@ -332,8 +373,9 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
         out["breakdown"] = {"device_ops": TRR.top_ops(summ),
                             "idle_gaps": TRR.idle_gaps(summ)}
         log(f"trace: {len(summ.ops)} device ops on {summ.devices}, window "
-            f"{summ.window_s:.3f}s, busy {ctx['busy_s']:.3f}s, reduced in "
-            f"{time.perf_counter() - t_red:.1f}s")
+            f"{summ.window_s:.3f}s, busy {ctx['busy_s']:.3f}s (by chip: "
+            + ", ".join(f"{b:.3f}" for b in by_chip)
+            + f"), reduced in {time.perf_counter() - t_red:.1f}s")
     else:
         e2e = {"p99_ms": ST.percentile(latency, 99) * 1e3,
                "p50_ms": ST.percentile(latency, 50) * 1e3,
@@ -352,6 +394,8 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     out["checks"] = chk["checks"]
 
     # --- the run's account, on standard error -----------------------------
+    by_host = np.bincount([rec[4] for rec in probes.launches],
+                          minlength=len(hosts)).tolist()
     mk = sess.marks
     log(f"cell {cell}: seed {seed}, {mix['loop']} loop, {n} requests "
         f"({len(set(sched.workloads))} classes), window {seconds}s"
@@ -360,7 +404,9 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
     log(f"compile cache: {sess.cache_dir}")
     log(f"set-up {setup_s:.2f}s: init {mk['init'] - T_START:.2f}s, build "
         f"{mk['built'] - mk['init']:.2f}s, warm-up {mk['warm'] - mk['built']:.2f}"
-        f"s ({mk['warm_launches']} launches; trace "
+        f"s (compiled for every chip {mk['compiled'] - mk['built']:.2f}s, "
+        f"other hosts {mk['warm'] - mk['compiled']:.2f}s; "
+        f"{mk['warm_launches']} launches; trace "
         f"{compile_s.get('trace', 0):.2f}s, lower "
         f"{compile_s.get('lower', 0):.2f}s, backend compile or cache fetch "
         f"{compile_s.get('backend_compile', 0):.2f}s in "
@@ -378,7 +424,8 @@ def window(sess: Session, seed: int, seconds: float, trace: bool,
         f"the window, last {tail * 1e3:.1f} ms after it), rejected "
         f"{int(rejected.sum())}, held for a full queue and retried "
         f"{out['retried']} (refusals {rejects}), launches "
-        f"{ctx['telemetry']['dispatches']}, host calls {win.calls}")
+        f"{ctx['telemetry']['dispatches']} (by host {by_host}), host calls "
+        f"{win.calls}")
     log(f"latency from due: p50 {ST.percentile(latency, 50) * 1e3:.3f} ms, "
         f"p99 {ST.percentile(latency, 99) * 1e3:.3f} ms, max "
         f"{latency.max() * 1e3:.3f} ms over {len(latency)} requests; median "

@@ -212,6 +212,63 @@ def test_open_loop_retries_a_full_queue_only(reason, answered):
         assert np.all(win.submitted == win.submitted[0])  # first attempt
 
 
+class _Fleet:
+    """Host slices behind a router that sends even tenants to host 0 and
+    odd ones to host 1; ``submit_many`` and ``pump`` go to every host."""
+
+    def __init__(self, hosts):
+        self.hosts = hosts
+        self.router = types.SimpleNamespace(host_for=lambda t: t % 2)
+
+    def submit_many(self, reqs, now):
+        out = [None] * len(reqs)
+        for h, host in enumerate(self.hosts):
+            pos = [p for p, r in enumerate(reqs) if r.tenant_id % 2 == h]
+            for p, handle in zip(pos, host.submit_many(
+                    [reqs[p] for p in pos], now)):
+                out[p] = handle
+        return out
+
+    def pump(self, now):
+        for host in self.hosts:
+            host.pump(now)
+
+    def next_deadline(self):
+        return None
+
+
+class _CountingServer(_BoundedServer):
+    """Also counts the submissions it refused."""
+
+    def __init__(self, probes, cap):
+        super().__init__(probes, cap, "queue_full")
+        self.refusals = 0
+
+    def submit_many(self, reqs, now):
+        hs = super().submit_many(reqs, now)
+        self.refusals += sum(h.rejected for h in hs)
+        return hs
+
+
+def test_open_loop_resubmits_only_into_the_owner_host():
+    """A request refused at its owner host's full queue is held until that
+    host has room, whatever room the other host has; each host serves its
+    own requests in due order."""
+    probes = _Probes()
+    full, roomy = _CountingServer(probes, cap=1), _CountingServer(probes,
+                                                                   cap=10)
+    fleet = _Fleet([full, roomy])
+    due = np.zeros(8)
+    win = H.run_open(fleet, [_Req(i) for i in range(8)], due, 0.1, probes)
+    assert all(r is not None for r in win.rows)
+    assert not win.rejected.any()
+    assert win.refused.tolist() == [False, False] + [True, False] * 3
+    # 2, 4 and 6 were refused once each, when all arrived together, and
+    # then each was submitted again only once host 0 had room for it
+    assert full.refusals == 3 and roomy.refusals == 0
+    assert np.all(np.diff(win.done[0::2]) > 0)
+
+
 def test_run_exits_nonzero_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("ALLOW_MULTIPLE_LIBTPU_LOAD", None)

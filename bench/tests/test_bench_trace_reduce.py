@@ -10,6 +10,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
+from bench import run as R  # noqa: E402
 from bench import trace_reduce as TRR  # noqa: E402
 
 TESTDATA = os.path.join(ROOT, "bench", "testdata")
@@ -60,6 +61,43 @@ def test_top_ops_and_idle_gaps(summary):
     assert [g[0] for g in gaps] == ["submit", "wait_arrival", "gather"]
     assert [g[1] for g in gaps] == pytest.approx([300e-9, 200e-9, 50e-9])
     assert TRR.host_span_at(summary.spans, 90) == "host_other"
+
+
+def four_chips(intervals):
+    """A summary over [100, 1100) ns whose chip n runs ``intervals[n]``."""
+    devices = [f"/device:TPU:{n}" for n in range(4)]
+    ops = [op(s, e - s, device=dev)
+           for dev, ivs in zip(devices, intervals) for s, e in ivs]
+    return TRR.Summary(window=(100, 1100), ops=ops, spans=[],
+                       devices=devices)
+
+
+@pytest.mark.parametrize("intervals,overlap,by_chip", [
+    # the chips take turns: one busy at a time
+    ([[(100, 300)], [(300, 500)], [(500, 700)], [(700, 900)]], 1.0,
+     [200, 200, 200, 200]),
+    # all four busy together, whenever any is
+    ([[(200, 600), (800, 900)]] * 4, 4.0, [500] * 4),
+    # clipped to the window: chip 0's 0..200 counts 100..200, chip 1's
+    # 1000..1500 counts 1000..1100, chip 2's op lies outside it
+    ([[(0, 200)], [(150, 250), (1000, 1500)], [(1200, 1300)], [(150, 200)]],
+     (100 + 200 + 50) / 250, [100, 200, 0, 50]),
+])
+def test_busy_by_chip_and_overlap(intervals, overlap, by_chip):
+    s = four_chips(intervals)
+    assert list(TRR.busy_by_device(s).values()) == pytest.approx(
+        [b * 1e-9 for b in by_chip])
+    assert TRR.busy_s(s) == pytest.approx(sum(by_chip) / 4 * 1e-9)
+    read = R.load_reader("fleet.busy_overlap")
+    assert read({"trace": s}) == pytest.approx(overlap)
+
+
+def test_busy_overlap_reads_nothing_without_device_time():
+    read = R.load_reader("fleet.busy_overlap")
+    assert read({"trace": None}) is None
+    assert read({"trace": four_chips([[(0, 50)], [], [], []])}) is None
+    no_device = TRR.Summary(window=(0, 10), ops=[], spans=[], devices=[])
+    assert read({"trace": no_device}) is None
 
 
 def _varint(n: int) -> bytes:

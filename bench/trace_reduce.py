@@ -14,7 +14,8 @@ What is read:
 
 What is computed (all in seconds, clipped to the ``window`` span):
 
-* ``busy_s``: the union of device-op intervals, averaged over the chips;
+* ``busy_s``: the union of device-op intervals, averaged over the chips
+  (``busy_by_device``: each chip's);
 * device time by named scope;
 * the device ops that took the most time, and the longest idle gaps of
   the device, each labelled with the host span open at its midpoint.
@@ -131,15 +132,18 @@ def union_ns(intervals, window: tuple) -> int:
     return total
 
 
+def busy_by_device(summary: Summary) -> dict:
+    """Each chip's busy seconds in the window: the union of its ops."""
+    return {dev: union_ns(((o.start, o.start + o.dur) for o in summary.ops
+                           if o.device == dev), summary.window) * 1e-9
+            for dev in summary.devices}
+
+
 def busy_s(summary: Summary) -> float:
     """Device busy seconds in the window, averaged over the chips."""
     if not summary.devices:
         return 0.0
-    total = 0
-    for dev in summary.devices:
-        total += union_ns(((o.start, o.start + o.dur) for o in summary.ops
-                           if o.device == dev), summary.window)
-    return total * 1e-9 / len(summary.devices)
+    return sum(busy_by_device(summary).values()) / len(summary.devices)
 
 
 def _in_window(summary: Summary):
