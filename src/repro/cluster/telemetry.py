@@ -174,7 +174,7 @@ def _merge_dispatch(snaps: list[dict]) -> dict:
     parts = [p for p in parts if p]
     out = {"dispatches": 0, "merged_dispatches": 0, "live_rows": 0,
            "launched_rows": 0, "donated": 0, "staged_bytes": 0,
-           "placements": 0}
+           "placements": 0, "transform_stages": 0, "field_reductions": 0}
     for p in parts:
         for k in out:
             out[k] += p.get(k, 0)

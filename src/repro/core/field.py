@@ -73,6 +73,10 @@ def shift8_mod(x, m):
     return _shiftk_mod(x, m, 8)
 
 
+def shift12_mod(x, m):
+    return _shiftk_mod(x, m, 12)
+
+
 def shift16_mod(x, m):
     return _shiftk_mod(x, m, 16)
 

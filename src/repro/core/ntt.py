@@ -7,6 +7,9 @@
 * ``morph_stage_matrices`` — the MORPH single-tenant baseline: the radix-2
   butterfly expressed as a sequence of log2(d) dense tile-resident GEMMs
   against permuted twiddle blocks (paper §7.2.1).
+* ``four_step_factors`` — the four-step (Bailey) split of a cyclic
+  transform into two √d-sized matrix transforms and a twiddle Hadamard, for
+  degrees whose dense matrix no longer fits a chip.
 """
 from __future__ import annotations
 
@@ -87,6 +90,58 @@ def matrix_ntt_oracle_np(a: np.ndarray, w: np.ndarray, m: int) -> np.ndarray:
     """Exact host oracle: (a @ W) mod m with Python bignums."""
     acc = a.astype(object) @ w.astype(object)
     return (acc % m).astype(np.uint32)
+
+
+# --- Four-step (Bailey) decomposition ----------------------------------------
+
+
+def four_step_split(d: int) -> tuple[int, int]:
+    """(d1, d2) = (2^⌈k/2⌉, 2^⌊k/2⌋) for d = 2^k: the sizes of the column
+    and row transforms of the four-step decomposition."""
+    if d < 4 or d & (d - 1):
+        raise ValueError(f"four-step transform needs a power of two d >= 4, "
+                         f"got {d}")
+    k = d.bit_length() - 1
+    return 1 << (k - k // 2), 1 << (k // 2)
+
+
+def four_step_factors(d: int, m: int) -> tuple:
+    """Host operands of the cyclic length-d transform mod m as a four-step.
+
+    With input index i = d2·i1 + i2 and output index j = j1 + d1·j2,
+    ω^(ij) = ω^(d2·i1·j1) · ω^(i2·j1) · ω^(d1·i2·j2), so
+
+    * stage 1: d2 column transforms of size d1 with W1[i1, j1] = ω^(d2·i1·j1);
+    * the twiddle Hadamard T[i2, j1] = ω^(i2·j1), shape (d2, d1);
+    * stage 2: d1 row transforms of size d2 with W2[i2, j2] = ω^(d1·i2·j2);
+
+    and X[j1 + d1·j2] is the stage-2 output at (j1, j2): a transpose back to
+    natural order.  Every factor is a power of the one primitive d-th root
+    ω, so the result is the same transform as ``ntt_matrix(d, m)``'s.
+    Returns ``(W1, W2, T)``, entries in [0, m) (object ints for m ≥ 2^32).
+    """
+    d1, d2 = four_step_split(d)
+    table = _power_table(_roots(m, d), d, m)
+
+    def powers(rows: int, cols: int, scale: int) -> np.ndarray:
+        i = np.arange(rows, dtype=np.int64)[:, None]
+        j = np.arange(cols, dtype=np.int64)[None, :]
+        return table[(scale * i * j) % d]
+
+    return powers(d1, d1, d2), powers(d2, d2, d1), powers(d2, d1, 1)
+
+
+def four_step_oracle_np(a: np.ndarray, m: int) -> np.ndarray:
+    """Exact host four-step of rows ``a`` (..., d) with Python bignums, the
+    decomposition itself (for tests): equals ``a @ ntt_matrix(d, m) mod m``."""
+    d = a.shape[-1]
+    d1, d2 = four_step_split(d)
+    w1, w2, t = (x.astype(object) for x in four_step_factors(d, m))
+    x = a.astype(object).reshape(a.shape[:-1] + (d1, d2))
+    x = np.swapaxes(x, -1, -2)                      # [..., i2, i1]
+    y = (x @ w1) % m * t % m                        # [..., i2, j1]
+    z = (np.swapaxes(y, -1, -2) @ w2) % m           # [..., j1, j2]
+    return np.swapaxes(z, -1, -2).reshape(a.shape)  # X[j1 + d1·j2]
 
 
 # --- O(d log d) Cooley-Tukey in pure JAX uint32 ------------------------------

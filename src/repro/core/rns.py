@@ -12,6 +12,8 @@ all inputs; CRT recovery of the integer value — and hence the F_p result — i
 exact whenever the true integer value stays below M = Π m_i (≈ 2**248 for the
 paper's 9-residue chain).  The extended 17-channel chain (``bn254_full``)
 makes full-range d≤256 polynomial products exact end-to-end.
+``field_to_rns`` re-encodes reduced field digits on the device, so a
+multi-stage transform can re-enter its envelope between stages.
 """
 from __future__ import annotations
 
@@ -149,3 +151,28 @@ def rns_to_field(residues, chain: RnsChain):
     acc = acc + comp
     y_digits = W.normalize_digits(acc)
     return MG.redc_digits(y_digits, chain)
+
+
+def field_to_rns(digits, chain: RnsChain):
+    """(..., nd) uint32 digit-12 of X  ->  (..., n+1) uint32 residues X mod m_c.
+
+    The device inverse of :func:`rns_to_field`'s output form: X mod m_c =
+    Σ_k digit_k · (β^k mod m_c).  The constants' own 12-bit digit planes t
+    = 0, 1, 2 make three int32 matmuls S_t = Σ_k digit_k · c_{k,t}, each
+    below nd · 2^24 < 2^31; per channel (S_2·2^24 + S_1·2^12 + S_0) mod m_c
+    then follows by Horner with conditional doublings.
+    """
+    nd = digits.shape[-1]
+    mods = np.array(chain.moduli, np.int64)
+    beta_k = np.array([[pow(1 << W.BETA_BITS, k, int(m)) for m in mods]
+                       for k in range(nd)], np.int64)        # (nd, n+1)
+    planes = [(beta_k >> (W.BETA_BITS * t)) & W.DIGIT_MASK for t in range(3)]
+    dig = digits.astype(jnp.int32)
+    sums = [jnp.matmul(dig, jnp.asarray(c.astype(np.int32)),
+                       preferred_element_type=jnp.int32).astype(jnp.uint32)
+            for c in planes]
+    m = jnp.asarray(mods.astype(np.uint32))
+    r = sums[2] % m
+    for s in (sums[1], sums[0]):
+        r = F.addmod_u32(F.shift12_mod(r, m), s % m, m)
+    return r

@@ -497,7 +497,9 @@ class SliceCoScheduler:
             "donated": self.donate, "lid": group.lid,
             "devices": self.device_ids(group.workload),
             "staged_bytes": int(operand_np.nbytes),
-            "placements": self.placements - placed})
+            "placements": self.placements - placed,
+            "transform_stages": eng.transform_stages,
+            "field_reductions": eng.field_reductions})
         return group, eng, out
 
     def _materialise(self, group: _LaunchGroup, eng, out):

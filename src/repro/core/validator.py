@@ -6,8 +6,12 @@ asserts the separation invariants against the *stock* XLA output:
   V1 (Invariant 5.1, strict reduction ordering): within each staged transform,
       every pass-k VPU fold is emitted after pass-k's MXU dot and before
       pass-(k+1)'s MXU dot — no reduction inside an open summation window.
+      A summation window is keyed by its stage (``fourstep_stage{s}`` of a
+      four-step program), channel and pass, so the same pass index of two
+      stages is never taken for one window.
   V2 (barrier survival): the lowered module carries one
-      ``optimization_barrier`` per adjacent staging-pass pair.
+      ``optimization_barrier`` per adjacent staging-pass pair — counted per
+      stage scope when the program has several.
   V3 (workload-zone fusion separation): no fused computation in the optimized
       HLO mixes ops from two distinct ``wzone_*`` scopes.
   V4 (precision-zone homogeneity): no fused computation mixes distinct
@@ -38,8 +42,13 @@ import jax
 
 WZONE_RE = re.compile(r"wzone_[A-Za-z0-9_]+")
 PZONE_RE = re.compile(r"pzone_[A-Za-z0-9_]+")
-PASS_RE = re.compile(r"staging_pass_(\d+)")
 OPNAME_RE = re.compile(r'op_name="([^"]*)"')
+# The transform stage of a four-step program; a dense program has one,
+# unnamed.
+STAGE_RE = re.compile(r"fourstep_stage\d+")
+# Summation-window key: stage, channel and pass.
+WINDOW_RE = re.compile(r"((?:fourstep_stage\d+/)?(?:channel_\d+/)?"
+                       r"staging_pass_\d+)")
 # Window key carries the channel qualifier so BN254's per-channel windows
 # with the same index stay distinct.
 LAZY_WIN_RE = re.compile(r"(?:channel_\d+/)?lazy_window_\d+(?=/vpu_fold_lazy)")
@@ -90,7 +99,7 @@ def _fusion_blocks(hlo_text: str) -> list[str]:
 
 
 def validate_module(lowered_text: str, compiled_text: str, *,
-                    expected_passes: int | None = None,
+                    expected_passes: int | dict | None = None,
                     expect_eager: bool = True,
                     expected_windows: int | None = None,
                     n_diag: int | None = None) -> ValidationReport:
@@ -137,44 +146,66 @@ def validate_module(lowered_text: str, compiled_text: str, *,
                         f"ops (expected {n_diag} — exactly one fold per "
                         f"window)"))
 
+    # --- V1/V2 read the lowered module's MLIR loc table ----------------------
+    # (debug_info=True) to name each op's scope path.
+    low_lines = lowered_text.splitlines()
+    loc_names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', lowered_text,
+                                re.M))
+    has_locs = bool(loc_names)
+
+    def _scope(ln: str) -> str:
+        m = re.search(r"loc\((#loc\d+)\)", ln)
+        return loc_names.get(m.group(1), "") if m else ""
+
     # --- V2: barrier survival in the lowered module --------------------------
+    # ``expected_passes``: one count for the program, or {stage scope: count}
+    # for a program of several transform stages, each checked on its own
+    # barriers where the loc table names them.
     n_barriers = len(re.findall(r"optimization_barrier", lowered_text))
-    if expect_eager and expected_passes and expected_passes > 1:
-        want = expected_passes - 1
-        if n_barriers < want:
-            violations.append((
-                "V2", f"{n_barriers} optimization_barriers for "
-                f"{expected_passes} staging passes (need >= {want})"))
+    if expect_eager and expected_passes:
+        want = (expected_passes if isinstance(expected_passes, dict)
+                else {"": expected_passes})
+        if len(want) > 1 and has_locs:
+            have: dict = {}
+            for ln in low_lines:
+                if "stablehlo.optimization_barrier" in ln:
+                    m = STAGE_RE.search(_scope(ln))
+                    key = m.group(0) if m else ""
+                    have[key] = have.get(key, 0) + 1
+        else:
+            have = {"": n_barriers}
+            want = {"": sum(p - 1 for p in want.values()) + 1}
+        for stage, passes in want.items():
+            if passes > 1 and have.get(stage, 0) < passes - 1:
+                violations.append((
+                    "V2", f"{have.get(stage, 0)} optimization_barriers for "
+                    f"{passes} staging passes{f' in {stage}' if stage else ''}"
+                    f" (need >= {passes - 1})"))
 
     # --- V1: strict reduction ordering (program order of the traced module) --
     # The lowered StableHLO preserves trace emission order (no hoisting yet):
     # between any two consecutive MXU summation windows (dot_general / pallas
     # kernel calls) there must be >= 1 modular-reduction op (stablehlo.remainder
     # from the fold) — i.e. no reduction is deferred into the next open
-    # summation, and no summation starts before the previous fold ran.
-    low_lines = lowered_text.splitlines()
+    # summation, and no summation starts before the previous fold ran.  Only
+    # *pointwise-phase* dots count as summation windows — the
+    # Montgomery/base-extension digit matmuls legitimately run fold-free
+    # (they ARE the reduction).
     dot_pat = re.compile(
         r"stablehlo\.dot_general|stablehlo\.custom_call.*(tpu_custom_call|pallas)")
-    # resolve the MLIR loc table (debug_info=True) so only *pointwise-phase*
-    # dots count as summation windows — the Montgomery/base-extension digit
-    # matmuls legitimately run fold-free (they ARE the reduction).
-    loc_names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', lowered_text,
-                                re.M))
-    has_locs = bool(loc_names)
 
     def _window_key(ln: str):
         """None if not a pointwise dot; else the summation-window scope key
-        (channel_i/staging_pass_k) — several partial-product dots inside one
-        pass share a window."""
+        (stage/channel/pass) — several partial-product dots inside one pass
+        share a window."""
         if not dot_pat.search(ln):
             return None
         if not has_locs:
             return "?"
-        m = re.search(r"loc\((#loc\d+)\)", ln)
-        name = loc_names.get(m.group(1), "") if m else ""
-        if m and name and "mxu_pointwise" not in name:
+        name = _scope(ln)
+        if name and "mxu_pointwise" not in name:
             return None  # Montgomery/base-extension matmul — not a window
-        wm = re.search(r"((channel_\d+/)?staging_pass_\d+)", name)
+        wm = WINDOW_RE.search(name)
         return wm.group(1) if wm else (name or "?")
 
     dots = [(i, _window_key(ln)) for i, ln in enumerate(low_lines)]
@@ -238,7 +269,7 @@ def validate_module(lowered_text: str, compiled_text: str, *,
         precision_zones=pzones_seen)
 
 
-def validate_fn(fn, *args, expected_passes: int | None = None,
+def validate_fn(fn, *args, expected_passes: int | dict | None = None,
                 expect_eager: bool = True, expected_windows: int | None = None,
                 n_diag: int | None = None,
                 donate_argnums=()) -> ValidationReport:

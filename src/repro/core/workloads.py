@@ -8,6 +8,10 @@
   multiplication: dual staging passes + in-GEMM matmul + >2,100
   base-extension Montgomery ops (paper §6.2).  ``n_channels=18`` selects the
   extended full-exactness chain (``bn254_full``).
+* ``BN254FourStepEngine`` — the same field transform at prover-scale
+  degrees (``bn254_prover``, d up to 2^16): a four-step NTT of two √d-sized
+  limb-GEMM stages on the 18-channel chain, re-entering F_p between them.
+  One op = one cyclic evaluation of a row of d field elements.
 
 Engines are pure-JAX modules; ``evaluate``/``reduce``/``e2e`` jit cleanly and
 are dispatched by the Tier-1/Tier-2 schedulers.
@@ -45,8 +49,13 @@ BN254 = WorkloadClass("bn254", precision_zone=4, data_limbs=4, tw_limbs=4,
                       n_channels=9)
 BN254_FULL = WorkloadClass("bn254_full", precision_zone=4, data_limbs=4,
                            tw_limbs=4, n_channels=18)
+BN254_PROVER = WorkloadClass("bn254_prover", precision_zone=4, data_limbs=4,
+                             tw_limbs=4, n_channels=18)
+BN254_PROVER9 = WorkloadClass("bn254_prover9", precision_zone=4,
+                              data_limbs=4, tw_limbs=4, n_channels=9)
 
-CLASSES = {c.name: c for c in (DILITHIUM, BN254, BN254_FULL)}
+CLASSES = {c.name: c for c in (DILITHIUM, BN254, BN254_FULL, BN254_PROVER,
+                               BN254_PROVER9)}
 
 
 def _fold_profile(plans, reduction: str, kappa: int | None,
@@ -83,6 +92,9 @@ class DilithiumEngine:
     """Forward negacyclic NTT over F_Q; exact end-to-end for all inputs."""
 
     wclass = DILITHIUM
+    # Per launch: limb-GEMM transform stages and RNS-to-field reductions.
+    transform_stages = 1
+    field_reductions = 0
 
     def __init__(self, d: int, *, accum: G.AccumModel = "fp32_mantissa",
                  reduction: G.Reduction = "eager", kappa: int | None = None,
@@ -139,6 +151,9 @@ class DilithiumEngine:
 
 class BN254Engine:
     """ERNS matrix transform + per-coefficient Montgomery reduction."""
+
+    transform_stages = 1
+    field_reductions = 1
 
     def __init__(self, d: int, *, accum: G.AccumModel = "fp32_mantissa",
                  reduction: G.Reduction = "eager", kappa: int | None = None,
@@ -225,6 +240,142 @@ class BN254Engine:
         return int(np.max(x)) < self.chain.M
 
 
+class BN254FourStepEngine:
+    """Prover-scale cyclic transform over F_p as a four-step (Bailey) NTT.
+
+    A dense d×d plan stops fitting a chip near d = 2^13; the four-step runs
+    the transform as two limb-GEMM stages of √d-sized plans
+    (:func:`repro.core.ntt.four_step_factors`).  Per channel, each stage is
+    a :func:`repro.core.limb_gemm.staged_transform`.  A stage sums
+    ``d1 · (p−1)²`` at most (2^516 at d = 2^16), inside the 18-channel
+    chain's M = 2^527, but a stage fed an unreduced stage-1 output would
+    not be, so the values re-enter F_p between the stages: ``rns_to_field``
+    after stage 1, after the twiddle product and after stage 2, with
+    ``field_to_rns`` re-encoding twice.  The output is ``(N, d, nred)``
+    uint16 digit-12 of X_j = Σ_i a_i ω^(ij) mod p, natural order.
+
+    ``n_channels=9`` (the paper's chain, M = 2^248 < p) is the class
+    ``bn254_prover9``: the same program whose every row is out of its
+    exactness envelope.
+    """
+
+    transform_stages = 2
+    field_reductions = 3
+
+    def __init__(self, d: int, *, accum: G.AccumModel = "fp32_mantissa",
+                 reduction: G.Reduction = "eager", kappa: int | None = None,
+                 d_tile: int | None = None, n_channels: int = 18,
+                 p: int = F.BN254_FR):
+        self.wclass = BN254_PROVER if n_channels == 18 else BN254_PROVER9
+        self.d = d
+        self.d1, self.d2 = NTT.four_step_split(d)
+        self.accum = accum
+        if G.check_reduction(reduction, kappa) != "eager":
+            raise ValueError("the four-step engine folds eagerly: its two "
+                             "stages' lazy windows are not validated")
+        self.d_tile = d_tile
+        self.chain = R.make_chain(n_channels, p=p)
+        w1, w2, tw = NTT.four_step_factors(d, p)
+        self.plans1 = self._plans(w1)
+        self.plans2 = self.plans1 if self.d1 == self.d2 else self._plans(w2)
+        self.twiddle = R.to_rns_np(tw, self.chain)           # (d2, d1, C)
+        p1 = _fold_profile(self.plans1, reduction, kappa, d_tile)
+        p2 = _fold_profile(self.plans2, reduction, kappa, d_tile)
+        self.fold_profile = dict(
+            p1, n_passes=p1["n_passes"] + p2["n_passes"],
+            windows_per_channel=(p1["windows_per_channel"]
+                                 + p2["windows_per_channel"]),
+            n_folds=p1["n_folds"] + p2["n_folds"],
+            contraction=self.d1 + self.d2,
+            stage_passes={"fourstep_stage1": p1["n_passes"],
+                          "fourstep_stage2": p2["n_passes"]})
+        self._device_planes = None
+
+    def _plans(self, w: np.ndarray) -> list:
+        return [G.make_channel_plan((w % m).astype(np.uint32), m,
+                                    data_limbs=4, tw_limbs=4,
+                                    accum=self.accum)
+                for m in self.chain.moduli]
+
+    @property
+    def n_channels(self) -> int:
+        return len(self.chain.moduli)
+
+    @property
+    def n_passes(self) -> int:
+        return self.fold_profile["n_passes"]
+
+    def ingest(self, coeffs_np: np.ndarray):
+        """Host object-int coefficients [..., d] -> (..., d, C) uint32."""
+        return jnp.asarray(R.to_rns_np(coeffs_np, self.chain))
+
+    def device_planes(self):
+        """Both stages' per-channel plane pairs and the twiddle residues,
+        uploaded to the device once per engine (stage 2 shares stage 1's
+        planes when d1 = d2)."""
+        if self._device_planes is None:
+            planes = {"stage1": [G.plane_operands(p) for p in self.plans1],
+                      "twiddle": jax.device_put(self.twiddle)}
+            if self.plans2 is not self.plans1:
+                planes["stage2"] = [G.plane_operands(p) for p in self.plans2]
+            self._device_planes = planes
+        return self._device_planes
+
+    def _stage(self, x, plans, planes):
+        """(rows, n, C) residues -> per-channel size-n transforms."""
+        outs = []
+        for ci, plan in enumerate(plans):
+            with jax.named_scope(f"channel_{ci}"):
+                y, self.last_stats = G.staged_transform(
+                    x[..., ci], plan, d_max=self.d_tile,
+                    planes=planes[ci] if planes is not None else None)
+            outs.append(y)
+        return jnp.stack(outs, axis=-1)
+
+    def reduce(self, y_res):
+        """(..., C) residues of values < M -> (..., nred) digits mod p."""
+        with jax.named_scope("vpu_montgomery"):
+            return R.rns_to_field(y_res, self.chain)
+
+    def _renormalise(self, y_res):
+        """Residues of a value < M -> residues of that value mod p, so that
+        the next product stays inside the envelope."""
+        digits = self.reduce(y_res)
+        with jax.named_scope("rns_encode"):
+            return R.field_to_rns(digits, self.chain)
+
+    def e2e(self, a_res, *, planes=None):
+        """(N, d, C) uint32 residues -> (N, d, nred) uint16 field digits.
+
+        The reductions run on the flat (N·d, C) layout, so all three share
+        one shape."""
+        n, d, d1, d2 = a_res.shape[0], self.d, self.d1, self.d2
+        c = self.n_channels
+        if planes is None:
+            p1, p2, tw = None, None, jnp.asarray(self.twiddle)
+        else:
+            p1, tw = planes["stage1"], planes["twiddle"]
+            p2 = planes.get("stage2", p1)
+        with jax.named_scope("wzone_bn254"), jax.named_scope("pzone_4limb"):
+            with jax.named_scope("fourstep_stage1"):
+                x = a_res.reshape(n, d1, d2, c).transpose(0, 2, 1, 3)
+                y = self._stage(x.reshape(n * d2, d1, c), self.plans1,
+                                p1)                          # [(n, i2), j1]
+            z = self._renormalise(y.reshape(n * d, c)).reshape(n, d, c)
+            with jax.named_scope("fourstep_twiddle"):
+                z = F.mulmod_u32(z, tw.reshape(d, c), jnp.asarray(
+                    np.array(self.chain.moduli, np.uint32)))
+            z = self._renormalise(z.reshape(n * d, c))
+            with jax.named_scope("fourstep_stage2"):
+                z = z.reshape(n, d2, d1, c).transpose(0, 2, 1, 3)
+                y = self._stage(z.reshape(n * d1, d2, c), self.plans2,
+                                p2)                          # [(n, j1), j2]
+            digits = self.reduce(y.reshape(n * d, c))
+            with jax.named_scope("fourstep_transpose"):
+                digits = digits.reshape(n, d1, d2, -1).transpose(0, 2, 1, 3)
+                return digits.reshape(n, d, -1).astype(jnp.uint16)
+
+
 @functools.lru_cache(maxsize=32)
 def make_engine(name: str, d: int, accum: str = "fp32_mantissa",
                 reduction: str = "eager", kappa: int | None = None,
@@ -236,4 +387,8 @@ def make_engine(name: str, d: int, accum: str = "fp32_mantissa",
         return BN254Engine(d, n_channels=9, **kw)
     if name == "bn254_full":
         return BN254Engine(d, n_channels=18, **kw)
+    if name == "bn254_prover":
+        return BN254FourStepEngine(d, n_channels=18, **kw)
+    if name == "bn254_prover9":
+        return BN254FourStepEngine(d, n_channels=9, **kw)
     raise KeyError(name)

@@ -31,7 +31,8 @@ to ``m_slots`` whole M tiles) over degree d with C channels costs
 
 * MXU: ``m_slots · d² · data_limbs · tw_limbs · C / MXU_MACS_PER_CYCLE``
   limb-plane GEMM MACs (the d² contraction is pass-tiled but its MAC count
-  is tile-invariant);
+  is tile-invariant; a four-step engine's profile gives its contraction
+  per coefficient, d1 + d2, in place of the second d);
 * VPU: ``n_folds · R · d · n_diag · VPU_OPS_PER_DIAG / VPU_LANES`` fold
   lane-ops (``n_folds`` already counts every channel's windows).
 """
@@ -75,7 +76,8 @@ def launch_cycles(*, d: int, live_rows: int, launched_rows: int,
     m_slots = -(-launched // m_tile) * m_tile
     k_occ = min(max(float(k_occupancy), 0.0), 1.0)
 
-    macs = (m_slots * float(d) * float(d) * profile["data_limbs"]
+    macs = (m_slots * float(d) * float(profile.get("contraction", d))
+            * profile["data_limbs"]
             * profile["tw_limbs"] * profile["n_channels"])
     mxu = macs / MXU_MACS_PER_CYCLE
     lane_ops = (profile["n_folds"] * launched * float(d)

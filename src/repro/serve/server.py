@@ -255,6 +255,19 @@ def coscheduler_from_config(cfg: ServeConfig, host: int | None = None,
         row_ladder=ladder, donate=cfg.donate, host=host, devices=devices)
 
 
+def _packing(eng, batch, n_c_max: int):
+    """A batch's packing metrics as its launch's GEMM sees them.  A dense
+    engine's K is the bucket.  A four-step engine's is its stage-1
+    sub-transform: d1 columns, of which a request of degree deg fills
+    ⌈deg/d2⌉ (stage 2's K is always full)."""
+    if hasattr(eng, "plans1"):
+        return packing_metrics([-(-deg // eng.d2) for deg in batch.degrees],
+                               eng.d1, eng.plans1[0].d_max, n_c_max=n_c_max)
+    d_max = eng.plan.d_max if hasattr(eng, "plan") else eng.plans[0].d_max
+    return packing_metrics(batch.degrees, batch.d_bucket, d_max,
+                           n_c_max=n_c_max)
+
+
 class CryptoServer:
     def __init__(self, config: ServeConfig | None = None, *,
                  coscheduler: SliceCoScheduler | None = None,
@@ -773,7 +786,9 @@ class CryptoServer:
             return eng.e2e(operand, planes=planes)
 
         if self.cos.reduction_for(batch.workload) == "eager":
-            rep = V.validate_fn(_e2e, *args, expected_passes=eng.n_passes,
+            # A four-step program's passes are counted per transform stage.
+            passes = eng.fold_profile.get("stage_passes", eng.n_passes)
+            rep = V.validate_fn(_e2e, *args, expected_passes=passes,
                                 donate_argnums=donate)
         else:
             # κ-amortised program: per-pass V1/V2 don't apply; instead assert
@@ -827,6 +842,12 @@ class CryptoServer:
                    "Operand bytes staged onto the device by launches.")
         m.describe("repro_stage_placements_total", "counter",
                    "Operand device_put calls of launches (one a launch).")
+        m.describe("repro_transform_stages_total", "counter",
+                   "Limb-GEMM transform stages of launches (1 dense, 2 "
+                   "four-step).")
+        m.describe("repro_field_reductions_total", "counter",
+                   "RNS-to-field reductions of launches (0 Dilithium, 1 "
+                   "dense BN254, 3 four-step).")
         m.describe("repro_dispatch_m_occupancy", "gauge",
                    "Mean achieved per-launch M occupancy (live/N_c_max).")
         m.describe("repro_latency_seconds", "gauge",
@@ -888,6 +909,10 @@ class CryptoServer:
                         (("reason", reason),), n))
         out.append(("repro_stage_bytes_total", (), live["staged_bytes"]))
         out.append(("repro_stage_placements_total", (), live["placements"]))
+        out.append(("repro_transform_stages_total", (),
+                    live["transform_stages"]))
+        out.append(("repro_field_reductions_total", (),
+                    live["field_reductions"]))
         if live["dispatches"]:
             out.append(("repro_dispatch_m_occupancy", (),
                         live["m_occupancy_sum"] / live["dispatches"]))
@@ -1215,10 +1240,7 @@ class CryptoServer:
         for cb in closed:
             batch = cb.batch
             eng = self.cos.engine_for(batch.workload, batch.d_bucket)
-            d_max = (eng.plan.d_max if hasattr(eng, "plan")
-                     else eng.plans[0].d_max)
-            m = packing_metrics(batch.degrees, batch.d_bucket, d_max,
-                                n_c_max=self.config.n_c_max)
+            m = _packing(eng, batch, self.config.n_c_max)
             batch_metrics.append((cb, eng, m))
             acc = class_k.setdefault((batch.workload, batch.d_bucket),
                                      [0.0, 0])
@@ -1261,7 +1283,9 @@ class CryptoServer:
                 donated=entry["donated"],
                 devices=tuple(entry.get("devices", ())),
                 staged_bytes=entry["staged_bytes"],
-                placements=entry["placements"]))
+                placements=entry["placements"],
+                transform_stages=entry["transform_stages"],
+                field_reductions=entry["field_reductions"]))
             acc = class_k.get(key)
             self.ledger.observe_launch(
                 workload=entry["workload"], d=entry["d_bucket"],

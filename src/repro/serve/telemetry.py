@@ -176,6 +176,8 @@ class DispatchRecord:
                              # for records predating device pinning)
     staged_bytes: int = 0    # host operand bytes put on the device
     placements: int = 0      # device_put calls that staged the operand
+    transform_stages: int = 0  # limb-GEMM transform stages of the program
+    field_reductions: int = 0  # RNS-to-field reductions of the program
 
 
 @dataclasses.dataclass(frozen=True)
@@ -230,6 +232,8 @@ class Telemetry:
             "launched_rows": 0,
             "staged_bytes": 0,
             "placements": 0,
+            "transform_stages": 0,
+            "field_reductions": 0,
             "m_occupancy_sum": 0.0,    # over DispatchRecords
             # phase -> [seconds, calls, longest]: the serving path's leaf
             # phases (repro.obs.tracing.Phases writes these in place)
@@ -265,6 +269,8 @@ class Telemetry:
         live["launched_rows"] += rec.launched_rows
         live["staged_bytes"] += rec.staged_bytes
         live["placements"] += rec.placements
+        live["transform_stages"] += rec.transform_stages
+        live["field_reductions"] += rec.field_reductions
         live["m_occupancy_sum"] += rec.m_occupancy
 
     def wait_record(self, workload: str) -> dict:
@@ -379,6 +385,10 @@ class Telemetry:
             "donated": sum(1 for r in self.dispatches if r.donated),
             "staged_bytes": sum(r.staged_bytes for r in self.dispatches),
             "placements": sum(r.placements for r in self.dispatches),
+            "transform_stages": sum(r.transform_stages
+                                    for r in self.dispatches),
+            "field_reductions": sum(r.field_reductions
+                                    for r in self.dispatches),
         }
         # Per-device launch census (device-parallel fleets): which device
         # ids this host's programs were enqueued on, and how many live rows

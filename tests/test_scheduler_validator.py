@@ -272,3 +272,92 @@ def test_fold_census_kappa():
         return y
     c_lazy = V.fold_census(lazy_fn, a)
     assert c_eager["n_fold_scopes"] > c_lazy["n_fold_scopes"] >= 0
+
+
+# --- V1/V2 keyed by transform stage (four-step programs) -----------------------
+
+STAGE_PASSES = {"fourstep_stage1": 3, "fourstep_stage2": 3}
+
+
+def _two_stage_fn(plan, *, stage2_barriers=True):
+    """Two staged transforms of one channel, as a four-step program nests
+    them: each under its ``fourstep_stage{s}`` scope."""
+    def fn(a):
+        with jax.named_scope("fourstep_stage1"):
+            y, _ = G.staged_transform(a, plan)
+        with jax.named_scope("fourstep_stage2"):
+            z, _ = G.staged_transform(y, plan, barriers=stage2_barriers)
+        return z
+    return fn
+
+
+def test_validator_accepts_two_stage_program(dil_plan_512):
+    a = jnp.zeros((8, 512), jnp.uint32)
+    rep = V.validate_fn(_two_stage_fn(dil_plan_512), a,
+                        expected_passes=STAGE_PASSES)
+    rep.raise_if_failed()
+
+
+def test_validator_counts_barriers_per_stage(dil_plan_512):
+    """Stage 2 without barriers: the module still holds stage 1's two, as
+    many as one 3-pass transform needs, but stage 2 has none."""
+    a = jnp.zeros((8, 512), jnp.uint32)
+    rep = V.validate_fn(_two_stage_fn(dil_plan_512, stage2_barriers=False),
+                        a, expected_passes=STAGE_PASSES)
+    assert [v[0] for v in rep.violations] == ["V2"]
+    assert "fourstep_stage2" in rep.violations[0][1]
+
+
+@pytest.mark.parametrize("moved", [False, True])
+def test_validator_flags_fold_moved_across_stage_boundary(dil_plan_512,
+                                                          moved):
+    """Stage 1's pass-0 fold emitted after stage 2's pass-0 summation: both
+    windows are ``staging_pass_0`` of one channel, told apart only by
+    their stage."""
+    plan = dil_plan_512
+    m = jnp.uint32(plan.modulus)
+    w_tile = jnp.asarray(plan.fused_operand[:171 * plan.data_limbs])
+
+    def fn(a):
+        def summation(stage):
+            with jax.named_scope(stage), jax.named_scope("staging_pass_0"):
+                return G.tile_diagonals(a[:, :171], None, w_tile, plan)
+
+        def fold(stage, diag):
+            with jax.named_scope(stage), jax.named_scope("staging_pass_0"), \
+                    jax.named_scope("vpu_fold"):
+                return F.fold_diagonals_u32(diag, m)
+
+        d1 = summation("fourstep_stage1")
+        if not moved:
+            y1 = fold("fourstep_stage1", d1)
+        d2 = summation("fourstep_stage2")
+        if moved:
+            y1 = fold("fourstep_stage1", d1)
+        return y1, fold("fourstep_stage2", d2)
+
+    rep = V.validate_fn(fn, jnp.zeros((8, 512), jnp.uint32),
+                        expected_passes={"fourstep_stage1": 1,
+                                         "fourstep_stage2": 1})
+    if not moved:
+        rep.raise_if_failed()
+        return
+    assert [v[0] for v in rep.violations] == ["V1"]
+    assert "fourstep_stage1/staging_pass_0→fourstep_stage2" in \
+        rep.violations[0][1]
+    with pytest.raises(V.ValidationError):
+        rep.raise_if_failed()
+
+
+def test_validator_accepts_four_step_engine():
+    """The served four-step program: V1 over both stages' windows, V2 per
+    stage, one workload and precision zone."""
+    eng = WK.make_engine("bn254_prover", 64, accum="int32_native",
+                         d_tile=171)
+    a = jnp.zeros((2, 64, eng.n_channels), jnp.uint32)
+    rep = V.validate_fn(lambda x, pl: eng.e2e(x, planes=pl), a,
+                        eng.device_planes(),
+                        expected_passes=eng.fold_profile["stage_passes"])
+    rep.raise_if_failed()
+    assert rep.zones == {"wzone_bn254"}
+    assert rep.precision_zones == {"pzone_4limb"}
